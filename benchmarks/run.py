@@ -52,6 +52,9 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown tables {unknown}; available: {list(TABLES)}")
     which = args.tables or list(TABLES)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     trace_buf = None
     if args.trace:
         from repro import obs

@@ -471,6 +471,23 @@ class RowShardedBoundSolve(BoundSolve):
         }
 
 
+def _auto_axes(mesh):
+    """``mesh`` with every axis typed ``AxisType.Auto``. A plain
+    ``jax.make_mesh(...)`` types its axes Explicit, under which the
+    host-side slicing of a sharded result (the batch/scratch trim, the
+    row-shard gather) raises instead of resolving its sharding; the
+    bound solves index their results freely, so they run on an Auto
+    view of the same devices."""
+    from jax.sharding import AxisType, Mesh
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(AxisType.Auto,) * len(mesh.axis_names),
+    )
+
+
 def _pad_cores(plan, model_ax: int):
     """Pad the plan's core axis UP to the mesh's ``model`` axis size so
     narrower schedules (e.g. serial's k=1 chains) shard cleanly — the
@@ -557,6 +574,7 @@ class DistributedBackend(Backend):
                 f"backend='distributed': unknown shard mode {shard!r} "
                 "(expected 'model' or 'rows')"
             )
+        mesh = _auto_axes(mesh)
         np_dtype = np.dtype(dtype)
         fused = self._fused(exec_plan, slack) if slack > 0 else None
         if shard == "rows":

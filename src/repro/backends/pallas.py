@@ -16,10 +16,15 @@ from repro.backends.registry import register_backend
 
 
 class PallasBoundSolve(BoundSolve):
+    """The kernel bound to one plan. ``elastic`` (the certificate from
+    ``core.elastic``) marks a ``mode="elastic"`` binding: the same kernel
+    with the tile size set to the slack window, so one grid step runs one
+    macro-step; bitwise-identical to the bulk binding of the plan."""
+
     backend = "pallas"
 
     def __init__(self, arrays, val_src, diag_src, *, n, n_entries,
-                 np_dtype, steps_per_tile, interpret):
+                 np_dtype, steps_per_tile, interpret, elastic=None):
         # arrays = (row_ids, col_idx, vals, diag, accum_mask), tile-padded
         self._arrays = arrays
         self._val_src = val_src  # int32[T_pad, k, W] device (-1 padded)
@@ -29,6 +34,11 @@ class PallasBoundSolve(BoundSolve):
         self._np_dtype = np_dtype
         self._steps_per_tile = steps_per_tile
         self._interpret = interpret
+        self._elastic = elastic
+        # runtime side of the elastic certificate (cf. the scan elastic
+        # bound): the kernel grid runs exactly n_macro_steps tiles per
+        # solve, so a timed solve records that many executed macro-steps
+        self._runtime = {"timed_solves": 0, "macro_steps_executed": 0}
 
     def solve(self, b):
         from repro.kernels.ops import solve_with_kernel_arrays
@@ -38,6 +48,18 @@ class PallasBoundSolve(BoundSolve):
             steps_per_tile=self._steps_per_tile,
             interpret=self._interpret, dtype=self._np_dtype,
         )
+
+    def solve_timed(self, b):
+        """Whole-solve timing (the kernel grid is one dispatch — there
+        is no host-visible per-tile boundary), plus the elastic runtime
+        bookkeeping ``describe()`` reports against the certificate."""
+        x, steps = super().solve_timed(b)
+        if self._elastic is not None:
+            self._runtime["timed_solves"] += 1
+            self._runtime["macro_steps_executed"] += (
+                self._elastic.n_macro_steps
+            )
+        return x, steps
 
     def update_values(self, data: np.ndarray) -> "PallasBoundSolve":
         import jax.numpy as jnp
@@ -61,12 +83,13 @@ class PallasBoundSolve(BoundSolve):
             np_dtype=self._np_dtype,
             steps_per_tile=self._steps_per_tile,
             interpret=self._interpret,
+            elastic=self._elastic,
         )
 
     def describe(self) -> dict:
         T, k = self._arrays[0].shape
         W = self._arrays[1].shape[-1]
-        return {
+        out = {
             "backend": self.backend,
             "n": self.n,
             "n_steps": T,  # tile-padded
@@ -80,125 +103,37 @@ class PallasBoundSolve(BoundSolve):
                     for a in self._arrays + (self._val_src, self._diag_src))
             ),
         }
-
-
-class ElasticPallasBoundSolve(BoundSolve):
-    """The ``mode="elastic"`` kernel bound: readiness waves replace the
-    per-step level barrier inside each tile (``sptrsv_pallas_elastic``),
-    bitwise-identical to ``PallasBoundSolve`` on the same plan."""
-
-    backend = "pallas"
-
-    def __init__(self, arrays, elastic, val_src, diag_src, *, n, n_entries,
-                 np_dtype, interpret):
-        # arrays = (wave_id, n_waves, row_ids, col_idx, vals, diag,
-        #           accum_mask), window-padded; tile size == slack
-        self._arrays = arrays
-        self._elastic = elastic  # core.elastic.ElasticPlan certificate
-        self._val_src = val_src
-        self._diag_src = diag_src
-        self.n = n
-        self.n_entries = n_entries
-        self._np_dtype = np_dtype
-        self._interpret = interpret
-        # runtime side of the elastic certificate (cf. the scan elastic
-        # bound): the kernel grid runs exactly n_macro_steps tiles per
-        # solve, so a timed solve records that many executed macro-steps
-        self._runtime = {"timed_solves": 0, "macro_steps_executed": 0}
-
-    def solve(self, b):
-        from repro.kernels.ops import solve_with_elastic_kernel_arrays
-
-        return solve_with_elastic_kernel_arrays(
-            self._arrays, b, n=self.n,
-            steps_per_tile=self._elastic.slack,
-            interpret=self._interpret, dtype=self._np_dtype,
-        )
-
-    def solve_timed(self, b):
-        """Whole-solve timing (the kernel grid is one dispatch — there
-        is no host-visible per-tile boundary), plus the elastic runtime
-        bookkeeping ``describe()`` reports against the certificate."""
-        x, steps = super().solve_timed(b)
-        self._runtime["timed_solves"] += 1
-        self._runtime["macro_steps_executed"] += self._elastic.n_macro_steps
-        return x, steps
-
-    def update_values(self, data: np.ndarray) -> "ElasticPallasBoundSolve":
-        import jax.numpy as jnp
-
-        with obs.span(
-            "backend.update_values", cat="backend", backend=self.backend
-        ):
-            data = jnp.asarray(
-                self._check_data(data).astype(self._np_dtype)
-            )
-            (
-                wave_id,
-                n_waves,
-                row_ids,
-                col_idx,
-                vals,
-                diag,
-                accum,
-            ) = self._arrays
-            vals, diag = masked_value_gather(
-                data, self._val_src, vals, self._diag_src, diag
-            )
-        return ElasticPallasBoundSolve(
-            (wave_id, n_waves, row_ids, col_idx, vals, diag, accum),
-            self._elastic,
-            self._val_src,
-            self._diag_src,
-            n=self.n,
-            n_entries=self.n_entries,
-            np_dtype=self._np_dtype,
-            interpret=self._interpret,
-        )
-
-    def describe(self) -> dict:
-        T, k = self._arrays[2].shape
-        W = self._arrays[3].shape[-1]
         ep = self._elastic
-        cert = ep.stats() if ep is not None else {}
+        if ep is None:
+            return out
+        cert = ep.stats()
         rt = dict(self._runtime)
         if rt["timed_solves"]:
             rt["macro_steps_per_solve"] = round(
                 rt["macro_steps_executed"] / rt["timed_solves"], 2
             )
-        return {
-            "backend": self.backend,
-            "mode": "elastic",
-            "n": self.n,
-            "n_steps": T,  # window-padded
-            "n_macro_steps": ep.n_macro_steps,
-            "slack": ep.slack,
-            "mean_waves_per_tile": float(ep.n_waves.mean()),
-            "k": k,
-            "W": W,
-            "dtype": np.dtype(self._np_dtype).name,
-            "steps_per_tile": ep.slack,
-            "interpret": bool(self._interpret),
-            "device_bytes": int(
-                sum(a.size * a.dtype.itemsize
-                    for a in self._arrays + (self._val_src, self._diag_src))
-            ),
-            "runtime": {
+        out.update(
+            mode="elastic",
+            n_macro_steps=ep.n_macro_steps,
+            slack=ep.slack,
+            runtime={
                 **rt,
                 "predicted_macro_steps": ep.n_macro_steps,
                 "predicted_barrier_fusion": cert.get("barrier_fusion"),
                 "predicted_step_fusion": cert.get("step_fusion"),
             },
-        }
+        )
+        return out
 
 
 @register_backend
 class PallasBackend(Backend):
     """Grid-of-tiles Pallas kernel; x resident in VMEM, plan tensors
-    streamed per tile. Interpret mode (CPU) executes the same kernel
-    logic through the Pallas interpreter. ``bind(slack=s)`` switches to
-    the readiness-wave elastic kernel (``"elastic"`` capability; the
-    tile size becomes the slack window)."""
+    streamed per tile. On a TPU the kernel always lowers through Mosaic;
+    elsewhere ``interpret`` defaults to True and the Pallas interpreter
+    executes the same kernel logic. ``bind(slack=s)`` runs the kernel
+    with the tile size set to the slack window (``"elastic"``
+    capability)."""
 
     name = "pallas"
 
@@ -235,30 +170,15 @@ class PallasBackend(Backend):
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         assert exec_plan.val_src is not None and exec_plan.diag_src is not None
+        ep = None
         if slack > 0:
             from repro.core.elastic import elastic_transform
 
             ep = exec_plan.elastic
             if ep is None or ep.slack != slack:
                 ep = elastic_transform(exec_plan, slack)
-            arrays = (
-                jnp.asarray(ep.wave_id.reshape(-1), jnp.int32),
-                jnp.asarray(ep.n_waves, jnp.int32),
-                *kernel_plan_arrays(exec_plan, steps_per_tile=slack,
-                                    dtype=dtype),
-            )
-            val_src = _pad_steps(exec_plan.val_src, slack, -1)
-            diag_src = _pad_steps(exec_plan.diag_src, slack, -1)
-            return ElasticPallasBoundSolve(
-                arrays,
-                ep,
-                jnp.asarray(val_src, jnp.int32),
-                jnp.asarray(diag_src, jnp.int32),
-                n=exec_plan.n,
-                n_entries=expected_entry_count(exec_plan),
-                np_dtype=np.dtype(dtype),
-                interpret=interpret,
-            )
+            # one grid step per macro-step: the tile IS the slack window
+            steps_per_tile = slack
         arrays = kernel_plan_arrays(
             exec_plan, steps_per_tile=steps_per_tile, dtype=dtype
         )
@@ -275,4 +195,5 @@ class PallasBackend(Backend):
             np_dtype=np.dtype(dtype),
             steps_per_tile=steps_per_tile,
             interpret=interpret,
+            elastic=ep,
         )

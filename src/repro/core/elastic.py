@@ -31,13 +31,14 @@ layer:
     ``ceil(T / slack)``.  Because the window is made of the *same* steps
     in the *same* order, each row's accumulation order is untouched and
     the result is bitwise-identical to the bulk-synchronous scan.
-  * **Waves** (Pallas kernel): within each window, consecutive steps
-    whose dependencies all resolve *before* the window join one
-    readiness wave (``wave_id``).  A wave's steps are mutually
-    independent, so the kernel's ``fori_loop`` iterates per *wave*
-    (``n_waves[w] <= slack``) with per-row readiness masks instead of
-    one iteration per step — per-row readiness flags replace the level
-    barrier.
+  * **Waves**: within each window, consecutive steps whose
+    dependencies all resolve *before* the window join one readiness
+    wave (``wave_id``).  A wave's steps are mutually independent, so a
+    vectorized kernel could run a whole wave per iteration
+    (``n_waves[w] <= slack``) instead of one iteration per step.  The
+    Pallas kernel runs a tile's rows one at a time on the scalar unit,
+    which has no level barrier to remove: its elastic binding only sets
+    the tile to the window, and no executor reads the waves today.
   * **Fused superstep bounds** (barrier certificate): runs of
     supersteps whose *cross-core* dependencies all resolve before the
     run starts, capped at ``slack`` supersteps per run.  A distributed
@@ -231,8 +232,8 @@ def elastic_transform(plan: ExecPlan, slack: int = DEFAULT_SLACK) -> ElasticPlan
 
     ``slack`` is the staleness window: the scan executor fuses runs of
     ``slack`` consecutive plan steps into one macro-step, the Pallas
-    kernel iterates readiness waves within that window, and fused
-    superstep runs are capped at ``slack`` supersteps.  Any ``slack >=
+    kernel runs one window per grid step, and fused superstep runs are
+    capped at ``slack`` supersteps.  Any ``slack >=
     1`` is valid — correctness never depends on the choice (the window
     replays the same steps in the same order), only the fused counts do.
     """

@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 ``sptrsv_kernel_solve(plan, b)`` is the drop-in replacement for
-``solver.executor.solve_with_plan`` backed by the Pallas kernel; on this
-CPU-only container it runs in interpret mode (the kernel body executes in
-Python), on TPU it lowers through Mosaic.
+``solver.executor.solve_with_plan`` backed by the Pallas kernel; on a TPU
+it lowers through Mosaic, on any other backend it runs in interpret mode
+(the kernel body executes as ordinary JAX ops).
 
 This module is the device half of the ``pallas`` entry in
 ``repro.backends`` — bind through the registry
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.plan import ExecPlan
-from repro.kernels.sptrsv import sptrsv_pallas, sptrsv_pallas_elastic
+from repro.kernels.sptrsv import sptrsv_pallas
 
 
 def _pad_steps(a: np.ndarray, mult: int, fill):
@@ -54,38 +54,6 @@ def solve_with_kernel_arrays(
     b = jnp.asarray(b, dtype=dtype)
     pad = jnp.zeros((1, *b.shape[1:]), dtype=dtype)
     x = sptrsv_pallas(
-        *arrays,
-        jnp.concatenate([b, pad]),
-        steps_per_tile=steps_per_tile,
-        interpret=interpret,
-    )
-    return x[:n]
-
-
-def elastic_kernel_arrays(plan: ExecPlan, *, dtype=jnp.float32):
-    """Plan + wave tensors for the elastic kernel. The tile size IS the
-    elastic slack window, so the certificate attached to the plan
-    (``plan.elastic``, from ``core.elastic.elastic_transform``) supplies
-    ``wave_id``/``n_waves`` directly and the step padding matches the
-    ``[M, slack]`` macro grid."""
-    ep = plan.elastic
-    assert ep is not None, "plan has no elastic certificate attached"
-    slack = ep.slack
-    return (
-        jnp.asarray(ep.wave_id.reshape(-1), jnp.int32),
-        jnp.asarray(ep.n_waves, jnp.int32),
-        *kernel_plan_arrays(plan, steps_per_tile=slack, dtype=dtype),
-    )
-
-
-def solve_with_elastic_kernel_arrays(
-    arrays, b, *, n: int, steps_per_tile: int, interpret: bool, dtype
-):
-    """Elastic twin of ``solve_with_kernel_arrays`` — same calling
-    convention over ``elastic_kernel_arrays`` output."""
-    b = jnp.asarray(b, dtype=dtype)
-    pad = jnp.zeros((1, *b.shape[1:]), dtype=dtype)
-    x = sptrsv_pallas_elastic(
         *arrays,
         jnp.concatenate([b, pad]),
         steps_per_tile=steps_per_tile,

@@ -1,36 +1,33 @@
 """Pallas TPU kernel for the scheduled SpTRSV executor.
 
-TPU-native design (DESIGN.md §3): the solution vector x lives in VMEM for
-the *entire* solve (input_output_aliasing on a (n+1,)-shaped buffer — 4 MB
-for n = 10^6 in f32, comfortably inside the 16 MB VMEM of a v5e core), while
-the plan tensors (row ids, column indices, values, diagonals) stream
-HBM -> VMEM one lock-step tile at a time via BlockSpecs. One grid step =
-``steps_per_tile`` sequential lock-step rows x k lanes. The grid dimension is
-sequential ("arbitrary"), which *is* the superstep chain: within a chip no
-barrier instruction exists or is needed between grid steps — exactly the
-L ~ 0 regime discussed in the paper's footnote 1.
+One kernel serves every binding: single- and multi-RHS (a single RHS is
+the m = 1 case) and both execution modes (``mode="elastic"`` runs the
+same kernel with the tile size set to the slack window).
 
-The k axis is sized to the VPU lane count (128) by the plan compiler for
-best utilization; W is the streamed gather width per row.
+Layout. The solution ``x`` and the right-hand side ``b`` live in VMEM for
+the whole solve as lane-dense ``(R, 128)`` arrays: slot ``c`` of an
+m-wide solve (m padded to a power of two <= 128) occupies lanes
+``[(c % spr) * m, (c % spr + 1) * m)`` of row ``c // spr``, with
+``spr = 128 // m`` slots per row. The plan tensors (row ids, column
+indices, values, diagonals, accumulate flags) stream HBM -> SMEM one tile
+of ``steps_per_tile`` lock-step rows at a time. The grid dimension is
+sequential ("arbitrary"), which *is* the superstep chain.
 
-Gather: x is addressed with per-lane dynamic indices. We express it as
-``jnp.take(x, cols)`` — Mosaic lowers int32 VMEM gathers natively on
-TPU >= v4 (dynamic-gather); correctness here is validated in interpret mode
-(this container is CPU-only).
+Per plan slot the scalar unit reads the column index and value from SMEM,
+loads the one x row that holds the slot (dynamic sublane index), rotates
+the slot's m lanes down to lane 0 and accumulates; a finishing row writes
+its m lanes back with a masked read-modify-write of one x row. Mosaic has
+no vector gather from VMEM at arbitrary addresses, so this per-element
+form is what lowers; it is not tuned for speed.
 
-Per-row recurrence inside a tile (sequential over the tile's rows):
-    acc   += sum_w vals[t, l, w] * x[col[t, l, w]]
-    x[row] = (b[row] - acc) / diag        (only on non-accum rows)
-The accumulator lives in a VMEM scratch buffer so it survives across grid
-steps (rows wider than W span tiles).
-
-``sptrsv_pallas_elastic`` is the ``mode="elastic"`` variant: instead of
-one ``fori_loop`` iteration per lock-step row (a level barrier inside
-the tile), it iterates the tile's *readiness waves* — runs of mutually
-independent steps certified by ``core.elastic.elastic_transform`` — with
-per-row readiness masks, so tiles whose rows are mostly independent
-finish in a handful of iterations. Bitwise-identical to the bulk kernel
-(the per-row accumulation order is untouched; see the kernel docstring).
+Arithmetic order per (row, RHS) is the scan executor's exactly
+(``solver.executor._step_single``): ``acc += v[w] * x[col[w]]`` for
+w = 0..W-1 in order, then ``(b[row] - acc) / diag``. Lanes of one step
+are independent rows of one superstep, so running them one after another
+reads the same values the scan's gather-then-scatter step reads. Padding
+lanes (row id n) are skipped: in the scan they only write +0.0 into the
+scratch slot, which the kernel's zero-initialized scratch slot already
+holds.
 """
 from __future__ import annotations
 
@@ -39,260 +36,168 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only namespace; absent on CPU builds is fine for interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+LANES = 128
+# Mosaic's default scoped-VMEM limit on v5e; the kernel asks for more
+# only when its resident x and b need it
+_DEFAULT_SCOPED_VMEM = 16 << 20
 
 
 def _sptrsv_kernel(
-    row_ref,  # int32[S, k]        (tile: S = steps_per_tile)
-    col_ref,  # int32[S, k, W]
-    val_ref,  # f[S, k, W]
-    diag_ref,  # f[S, k]
-    accum_ref,  # f[S, k]  (0.0 / 1.0 mask; bool blocks are awkward on TPU)
-    b_ref,  # f[n+1]  (resident)
-    x_in_ref,  # f[n+1]  (the donated zero buffer; same memory as x_ref)
-    x_ref,  # f[n+1]  (aliased in/out, resident)
-    acc_ref,  # f[k] scratch — carries partial sums across tiles
+    row_ref,  # int32[S, k]     SMEM tile
+    col_ref,  # int32[S, k*W]   SMEM tile
+    val_ref,  # f[S, k*W]       SMEM tile
+    diag_ref,  # f[S, k]        SMEM tile
+    accum_ref,  # f[S, k]       SMEM tile (0.0 / 1.0)
+    b_ref,  # f[R, 128]         VMEM, resident
+    x_ref,  # f[R, 128]         VMEM, resident output
+    acc_ref,  # f[k8, 128]      VMEM scratch: per-lane partial sums
     *,
     steps_per_tile: int,
+    k: int,
+    W: int,
+    m: int,
+    n: int,
 ):
-    del x_in_ref  # aliased with x_ref; all access goes through the output ref
-    first = pl.program_id(0) == 0
+    spr = LANES // m
+    shift = spr.bit_length() - 1  # spr is a power of two
 
-    @pl.when(first)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # x starts as zeros; the aliased input is pre-zeroed by the wrapper.
-
-    def body(t, _):
-        rows = row_ref[t]  # int32[k]
-        cols = col_ref[t]  # int32[k, W]
-        v = val_ref[t]  # f[k, W]
-        d = diag_ref[t]
-        a = accum_ref[t]
-        x = x_ref[...]
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-        # repro: blessed-reduction — W-axis dot within one lane: the
-        # operand set is fixed per (row, lane) regardless of k/shard, so
-        # reassociation cannot cross lanes (bitwise-checked vs the scan
-        # oracle in tests/test_kernels.py)
-        acc = acc_ref[...] + jnp.sum(v * gathered, axis=-1)
-        b_rows = jnp.take(b_ref[...], rows, axis=0)
-        xv = (b_rows - acc) / d
-        keep = a > 0.5  # still accumulating
-        old = jnp.take(x, rows, axis=0)
-        write = jnp.where(keep, old, xv)
-        x_ref[...] = x.at[rows].set(write)
-        acc_ref[...] = jnp.where(keep, acc, 0.0)
-        return ()
-
-    jax.lax.fori_loop(0, steps_per_tile, body, ())
-
-
-def _sptrsv_mrhs_kernel(
-    row_ref,  # int32[S, k]
-    col_ref,  # int32[S, k, W]
-    val_ref,  # f[S, k, W]
-    diag_ref,  # f[S, k]
-    accum_ref,  # f[S, k]
-    b_ref,  # f[n+1, m]  (resident; m RHS lane-major)
-    x_in_ref,  # f[n+1, m]
-    x_ref,  # f[n+1, m]  (aliased in/out, resident)
-    acc_ref,  # f[k, m] scratch — per-lane, per-RHS partial sums
-    *,
-    steps_per_tile: int,
-):
-    """Multi-RHS variant: identical control flow to ``_sptrsv_kernel``, but
-    every x slot is a length-m vector (RHS index = minor/lane axis, so the
-    m solves share one gather of indices and widen only the value lanes)."""
-    del x_in_ref
-    first = pl.program_id(0) == 0
-
-    @pl.when(first)
-    def _init():
+        x_ref[...] = jnp.zeros_like(x_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def body(t, _):
-        rows = row_ref[t]  # int32[k]
-        cols = col_ref[t]  # int32[k, W]
-        v = val_ref[t]  # f[k, W]
-        d = diag_ref[t]
-        a = accum_ref[t]
-        x = x_ref[...]  # f[n+1, m]
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(*cols.shape, -1)
-        # repro: blessed-reduction — W-axis dot within one lane: the
-        # operand set is fixed per (row, lane) regardless of k/shard, so
-        # reassociation cannot cross lanes (bitwise-checked vs the scan
-        # oracle in tests/test_kernels.py)
-        acc = acc_ref[...] + jnp.sum(v[..., None] * gathered, axis=1)
-        b_rows = jnp.take(b_ref[...], rows, axis=0)  # f[k, m]
-        xv = (b_rows - acc) / d[:, None]
-        keep = (a > 0.5)[:, None]  # still accumulating
-        old = jnp.take(x, rows, axis=0)
-        write = jnp.where(keep, old, xv)
-        x_ref[...] = x.at[rows].set(write)
-        acc_ref[...] = jnp.where(keep, acc, 0.0)
-        return ()
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    jax.lax.fori_loop(0, steps_per_tile, body, ())
+    def load_slot(ref, c):
+        """Slot ``c``'s m values, rotated down to lanes [0, m)."""
+        v = ref[pl.ds(c >> shift, 1), :]
+        if spr == 1:
+            return v
+        off = (c & (spr - 1)) * m
+        return pltpu.roll(v, (LANES - off) & (LANES - 1), 1)
+
+    def store_slot(ref, c, v):
+        """Write lanes [0, m) of ``v`` into slot ``c``; the row's other
+        slots keep their bits."""
+        r = c >> shift
+        if spr == 1:
+            ref[pl.ds(r, 1), :] = v
+            return
+        off = (c & (spr - 1)) * m
+        v = pltpu.roll(v, off, 1)
+        mine = (lane >= off) & (lane < off + m)
+        ref[pl.ds(r, 1), :] = jnp.where(mine, v, ref[pl.ds(r, 1), :])
+
+    def lane_body(t, l):
+        row = row_ref[t, l]
+
+        @pl.when(row != n)
+        def _():
+            acc = acc_ref[pl.ds(l, 1), :]
+            for w in range(W):
+                acc = acc + val_ref[t, l * W + w] * load_slot(
+                    x_ref, col_ref[t, l * W + w]
+                )
+            keep = accum_ref[t, l] > 0.5  # row continues in the next step
+
+            @pl.when(keep)
+            def _carry():
+                acc_ref[pl.ds(l, 1), :] = acc
+
+            @pl.when(jnp.logical_not(keep))
+            def _finish():
+                xv = (load_slot(b_ref, row) - acc) / diag_ref[t, l]
+                store_slot(x_ref, row, xv)
+                acc_ref[pl.ds(l, 1), :] = jnp.zeros_like(acc)
+
+    def step(t, carry):
+        def lane_loop(l, c):
+            lane_body(t, l)
+            return c
+
+        return jax.lax.fori_loop(0, k, lane_loop, carry)
+
+    jax.lax.fori_loop(0, steps_per_tile, step, ())
 
 
-def _sptrsv_elastic_kernel(
-    wave_ref,  # int32[S]  readiness wave of each in-tile step
-    nw_ref,  # int32[1]  number of waves in this tile
-    row_ref,  # int32[S, k]
-    col_ref,  # int32[S, k, W]
-    val_ref,  # f[S, k, W]
-    diag_ref,  # f[S, k]
-    accum_ref,  # f[S, k]  (0/1 mask)
-    b_ref,  # f[n+1]  (resident)
-    x_in_ref,  # f[n+1]  (donated zero buffer, aliased with x_ref)
-    x_ref,  # f[n+1]  (aliased in/out, resident)
-    acc_ref,  # f[k] scratch — selected accumulator entering the tile
-    tot_ref,  # f[S, k] scratch — per-step running totals within the tile
-    *,
-    steps_per_tile: int,
-):
-    """Elastic tile body: per-row readiness waves instead of one
-    ``fori_loop`` iteration per lock-step row.
+def _pow2_at_least(m: int) -> int:
+    return 1 << max(m - 1, 0).bit_length()
 
-    The elastic transform (core.elastic) certifies that within a tile,
-    consecutive steps sharing a ``wave_id`` are mutually independent —
-    their gather columns were all written before the wave starts and no
-    accumulator chain crosses into them. The loop therefore iterates
-    ``n_waves <= steps_per_tile`` times (the traced bound lowers to a
-    while loop), each iteration processing a whole wave of rows at once
-    under a readiness mask — on wide-wave tiles this replaces the level
-    barrier (one iteration per step) with far fewer iterations.
 
-    Bitwise equality with the bulk kernel: each step's partial sum is
-    still ``sum_w v * x[col]`` reduced in the same lane order, and the
-    accumulator entering step s is *selected*, never re-summed — step
-    s reads ``tot_ref[s-1]`` iff step s-1 accumulates (same-lane chain,
-    forced into an earlier wave), else the zero the bulk kernel would
-    also hold. Stale ``tot_ref`` rows are never selected: a same-wave
-    predecessor cannot carry ``accum`` by the wave-break rule.
-    """
-    del x_in_ref
-    first = pl.program_id(0) == 0
+def lane_rows(n_slots: int, m: int) -> int:
+    """Rows of the ``(R, 128)`` VMEM layout holding ``n_slots`` slots of
+    an m-wide solve (m a power of two <= 128), padded to a sublane
+    multiple."""
+    spr = LANES // m
+    return -(-(-(-n_slots // spr)) // 8) * 8
 
-    @pl.when(first)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-    # tot_ref needs no init: rows are only read behind an accum flag,
-    # which certifies the row was written in an earlier wave of THIS tile
 
-    rows = row_ref[...]  # int32[S, k]
-    aflag = accum_ref[...] > 0.5  # bool[S, k]
-    waves = wave_ref[...]  # int32[S]
-    n_slot = x_ref.shape[0] - 1
-
-    def wave(r, _):
-        x = x_ref[...]
-        sel = waves == r  # bool[S]
-        cols = col_ref[...]
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(cols.shape)
-        # repro: blessed-reduction — W-axis dot within one lane: the
-        # operand set is fixed per (row, lane) regardless of k/shard, so
-        # reassociation cannot cross lanes (bitwise-checked vs the scan
-        # oracle in tests/test_kernels.py)
-        ps = jnp.sum(val_ref[...] * gathered, axis=-1)  # f[S, k]
-        tot_prev = tot_ref[...]
-        # accumulator entering step s: the tile carry for s = 0, else
-        # step s-1's total iff s-1 is an accum step (same-lane chain)
-        sel_acc = jnp.concatenate(
-            [acc_ref[...][None], jnp.where(aflag[:-1], tot_prev[:-1], 0.0)],
-            axis=0,
-        )
-        tot = sel_acc + ps
-        b_rows = jnp.take(b_ref[...], rows.reshape(-1), axis=0).reshape(rows.shape)
-        xv = (b_rows - tot) / diag_ref[...]
-        live = sel[:, None] & ~aflag  # rows finalized by this wave
-        safe = jnp.where(live, rows, n_slot)  # off-wave lanes hit scratch
-        x_ref[...] = x.at[safe.reshape(-1)].set(
-            jnp.where(live, xv, 0.0).reshape(-1)
-        )
-        tot_ref[...] = jnp.where(sel[:, None], tot, tot_prev)
-        return ()
-
-    jax.lax.fori_loop(0, nw_ref[0], wave, ())
-    # tile carry: the last step's total iff it accumulates into the next
-    # tile (virtual-row chains are same-lane consecutive steps)
-    acc_ref[...] = jnp.where(
-        aflag[steps_per_tile - 1], tot_ref[steps_per_tile - 1], 0.0
+def vmem_bytes(n_slots: int, m: int, k: int, itemsize: int = 4) -> int:
+    """VMEM the kernel keeps resident: x and b in the lane layout plus
+    the per-lane accumulator."""
+    return (2 * lane_rows(n_slots, m) + max(8, -(-k // 8) * 8)) * (
+        LANES * itemsize
     )
 
 
-def _sptrsv_elastic_mrhs_kernel(
-    wave_ref,  # int32[S]
-    nw_ref,  # int32[1]
-    row_ref,  # int32[S, k]
-    col_ref,  # int32[S, k, W]
-    val_ref,  # f[S, k, W]
-    diag_ref,  # f[S, k]
-    accum_ref,  # f[S, k]
-    b_ref,  # f[n+1, m]  (resident)
-    x_in_ref,  # f[n+1, m]
-    x_ref,  # f[n+1, m]  (aliased in/out, resident)
-    acc_ref,  # f[k, m] scratch
-    tot_ref,  # f[S, k, m] scratch
-    *,
-    steps_per_tile: int,
-):
-    """Multi-RHS twin of ``_sptrsv_elastic_kernel`` (x slots widen to m)."""
-    del x_in_ref
-    first = pl.program_id(0) == 0
+def _solve_block(row_ids, col_idx, vals, diag, accum_mask, b_pad, *,
+                 steps_per_tile, interpret):
+    """One kernel call for an m <= 128 wide ``b_pad`` f[n+1, m]."""
+    T, k, W = col_idx.shape
+    n1, m = b_pad.shape
+    mp = _pow2_at_least(m)
+    R = lane_rows(n1, mp)
+    dt = vals.dtype
+    b = jnp.pad(b_pad.astype(dt), ((0, 0), (0, mp - m)))
+    b = jnp.pad(b.reshape(-1), (0, R * LANES - n1 * mp)).reshape(R, LANES)
 
-    @pl.when(first)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    rows = row_ref[...]
-    aflag = accum_ref[...] > 0.5
-    waves = wave_ref[...]
-    n_slot = x_ref.shape[0] - 1
-
-    def wave(r, _):
-        x = x_ref[...]  # f[n+1, m]
-        sel = waves == r
-        cols = col_ref[...]
-        gathered = jnp.take(x, cols.reshape(-1), axis=0).reshape(*cols.shape, -1)
-        # repro: blessed-reduction — W-axis dot within one lane: the
-        # operand set is fixed per (row, lane) regardless of k/shard, so
-        # reassociation cannot cross lanes (bitwise-checked vs the scan
-        # oracle in tests/test_kernels.py)
-        ps = jnp.sum(val_ref[...][..., None] * gathered, axis=2)  # f[S, k, m]
-        tot_prev = tot_ref[...]
-        sel_acc = jnp.concatenate(
-            [
-                acc_ref[...][None],
-                jnp.where(aflag[:-1, :, None], tot_prev[:-1], 0.0),
-            ],
-            axis=0,
+    def smem_tile(cols):
+        return pl.BlockSpec(
+            (steps_per_tile, cols), lambda i: (i, 0),
+            memory_space=pltpu.SMEM,
         )
-        tot = sel_acc + ps
-        b_rows = jnp.take(b_ref[...], rows.reshape(-1), axis=0).reshape(
-            *rows.shape, -1
-        )
-        xv = (b_rows - tot) / diag_ref[...][..., None]
-        live = sel[:, None] & ~aflag
-        safe = jnp.where(live, rows, n_slot)
-        x_ref[...] = x.at[safe.reshape(-1)].set(
-            jnp.where(live[..., None], xv, 0.0).reshape(-1, xv.shape[-1])
-        )
-        tot_ref[...] = jnp.where(sel[:, None, None], tot, tot_prev)
-        return ()
 
-    jax.lax.fori_loop(0, nw_ref[0], wave, ())
-    acc_ref[...] = jnp.where(
-        aflag[steps_per_tile - 1][:, None], tot_ref[steps_per_tile - 1], 0.0
+    resident = pl.BlockSpec(memory_space=pltpu.VMEM)
+    compiler_params = None
+    if not interpret:
+        need = vmem_bytes(n1, mp, k, jnp.dtype(dt).itemsize)
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),  # sequential grid = chain
+            vmem_limit_bytes=(
+                need + (2 << 20) if need > _DEFAULT_SCOPED_VMEM else None
+            ),
+        )
+    x = pl.pallas_call(
+        functools.partial(
+            _sptrsv_kernel, steps_per_tile=steps_per_tile, k=k, W=W, m=mp,
+            n=n1 - 1,
+        ),
+        grid=(T // steps_per_tile,),
+        in_specs=[
+            smem_tile(k),  # row_ids
+            smem_tile(k * W),  # col_idx
+            smem_tile(k * W),  # vals
+            smem_tile(k),  # diag
+            smem_tile(k),  # accum mask
+            resident,  # b
+        ],
+        out_specs=resident,  # x
+        out_shape=jax.ShapeDtypeStruct((R, LANES), dt),
+        scratch_shapes=[pltpu.VMEM((max(8, -(-k // 8) * 8), LANES), dt)],
+        interpret=interpret,
+        compiler_params=compiler_params,
+        name="sptrsv_tile",
+    )(
+        row_ids,
+        col_idx.reshape(T, k * W),
+        vals.reshape(T, k * W),
+        diag,
+        accum_mask,
+        b,
     )
+    return x.reshape(-1)[: n1 * mp].reshape(n1, mp)[:, :m]
 
 
 @functools.partial(
@@ -311,135 +216,18 @@ def sptrsv_pallas(
     interpret: bool = False,
 ):
     """Run the full scheduled solve; returns x shaped like ``b_pad`` (last
-    row is scratch). A 2-D ``b_pad`` solves all m RHS in one pass."""
-    T, k = row_ids.shape
-    W = col_idx.shape[-1]
+    row is scratch). A 2-D ``b_pad`` solves all m RHS in one pass per
+    block of 128 columns."""
+    T = row_ids.shape[0]
     assert T % steps_per_tile == 0, "pad T to a multiple of steps_per_tile"
-    n_tiles = T // steps_per_tile
-    multi_rhs = b_pad.ndim == 2
-    x0 = jnp.zeros_like(b_pad)
-
-    grid = (n_tiles,)
-    tile = lambda *tail: pl.BlockSpec(  # noqa: E731
-        (steps_per_tile, *tail), lambda i: (i, *([0] * len(tail)))
-    )
-    resident = pl.BlockSpec(b_pad.shape, lambda i: (0,) * b_pad.ndim)
-
-    if multi_rhs:
-        kernel = functools.partial(
-            _sptrsv_mrhs_kernel, steps_per_tile=steps_per_tile
+    single = b_pad.ndim == 1
+    b2 = b_pad[:, None] if single else b_pad
+    blocks = [
+        _solve_block(
+            row_ids, col_idx, vals, diag, accum_mask, b2[:, j: j + LANES],
+            steps_per_tile=steps_per_tile, interpret=interpret,
         )
-        acc_shape = (k, b_pad.shape[1])
-    else:
-        kernel = functools.partial(_sptrsv_kernel, steps_per_tile=steps_per_tile)
-        acc_shape = (k,)
-    # pltpu.VMEM scratch persists across (sequential) grid steps — the
-    # accumulator for rows split over multiple tiles. Interpret mode honours
-    # it on CPU.
-    assert _VMEM is not None, "pltpu namespace unavailable"
-    scratch_shapes = [_VMEM(acc_shape, vals.dtype)]
-
-    compiler_params = None
-    if not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),  # sequential grid = chain
-        )
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            tile(k),  # row_ids
-            tile(k, W),  # col_idx
-            tile(k, W),  # vals
-            tile(k),  # diag
-            tile(k),  # accum mask
-            resident,  # b
-            resident,  # x0 (aliased with the output)
-        ],
-        out_specs=resident,  # x
-        out_shape=jax.ShapeDtypeStruct(b_pad.shape, vals.dtype),
-        input_output_aliases={6: 0},  # x0 (7th arg) <-> output
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-        compiler_params=compiler_params,
-    )(row_ids, col_idx, vals, diag, accum_mask, b_pad, x0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("steps_per_tile", "interpret"),
-)
-def sptrsv_pallas_elastic(
-    wave_id,  # int32[T]  readiness wave of each step within its tile
-    n_waves,  # int32[n_tiles]  waves per tile
-    row_ids,  # int32[T, k]
-    col_idx,  # int32[T, k, W]
-    vals,  # f[T, k, W]
-    diag,  # f[T, k]
-    accum_mask,  # f[T, k] (0/1)
-    b_pad,  # f[n+1] or f[n+1, m]
-    *,
-    steps_per_tile: int = 8,
-    interpret: bool = False,
-):
-    """Elastic scheduled solve: per-row readiness waves replace the level
-    barrier inside each tile (see ``_sptrsv_elastic_kernel``). The tile
-    size must equal the elastic transform's slack window — ``wave_id`` /
-    ``n_waves`` come from ``core.elastic.elastic_transform(plan, slack)``
-    with ``slack == steps_per_tile``. Returns x shaped like ``b_pad``."""
-    T, k = row_ids.shape
-    W = col_idx.shape[-1]
-    assert T % steps_per_tile == 0, "pad T to a multiple of steps_per_tile"
-    n_tiles = T // steps_per_tile
-    multi_rhs = b_pad.ndim == 2
-    x0 = jnp.zeros_like(b_pad)
-
-    grid = (n_tiles,)
-    tile = lambda *tail: pl.BlockSpec(  # noqa: E731
-        (steps_per_tile, *tail), lambda i: (i, *([0] * len(tail)))
-    )
-    resident = pl.BlockSpec(b_pad.shape, lambda i: (0,) * b_pad.ndim)
-
-    if multi_rhs:
-        kernel = functools.partial(
-            _sptrsv_elastic_mrhs_kernel, steps_per_tile=steps_per_tile
-        )
-        acc_shape = (k, b_pad.shape[1])
-        tot_shape = (steps_per_tile, k, b_pad.shape[1])
-    else:
-        kernel = functools.partial(
-            _sptrsv_elastic_kernel, steps_per_tile=steps_per_tile
-        )
-        acc_shape = (k,)
-        tot_shape = (steps_per_tile, k)
-    assert _VMEM is not None, "pltpu namespace unavailable"
-    scratch_shapes = [_VMEM(acc_shape, vals.dtype), _VMEM(tot_shape, vals.dtype)]
-
-    compiler_params = None
-    if not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),  # sequential grid = chain
-        )
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((steps_per_tile,), lambda i: (i,)),  # wave_id
-            pl.BlockSpec((1,), lambda i: (i,)),  # n_waves
-            tile(k),  # row_ids
-            tile(k, W),  # col_idx
-            tile(k, W),  # vals
-            tile(k),  # diag
-            tile(k),  # accum mask
-            resident,  # b
-            resident,  # x0 (aliased with the output)
-        ],
-        out_specs=resident,  # x
-        out_shape=jax.ShapeDtypeStruct(b_pad.shape, vals.dtype),
-        input_output_aliases={8: 0},  # x0 (9th arg) <-> output
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-        compiler_params=compiler_params,
-    )(wave_id, n_waves, row_ids, col_idx, vals, diag, accum_mask, b_pad, x0)
+        for j in range(0, b2.shape[1], LANES)
+    ]
+    x = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+    return x[:, 0] if single else x

@@ -119,10 +119,10 @@ def main(argv=None) -> None:
     )
     ap.add_argument("--json", metavar="PATH", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     plan_kw = {}
-    if args.backend == "pallas":
-        plan_kw["interpret"] = True  # CPU containers have no TPU
     if args.backend == "distributed":
         mesh = _make_mesh(args.mesh)
         plan_kw["mesh"] = mesh
@@ -188,13 +188,15 @@ def main(argv=None) -> None:
                 f"still alive after timeout, "
                 f"{close_report['pins_retained']} plan pins retained]"
             )
-    if args.validate and (report["bitwise_mismatches"] or report["errors"]):
-        raise SystemExit("validation failed")
     if args.json:
         with open(args.json, "w") as fh:
             json.dump(report, fh, indent=2)
             fh.write("\n")
         print(f"[json written to {args.json}]")
+    if report["errors"]:
+        raise SystemExit(f"{report['errors']} request(s) errored")
+    if args.validate and report["bitwise_mismatches"]:
+        raise SystemExit("validation failed")
 
 
 if __name__ == "__main__":

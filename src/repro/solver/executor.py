@@ -108,45 +108,71 @@ def _scan_single(row_ids, col_idx, vals, diag, accum, b_pad, n):
     return x[:n]
 
 
-# the single-RHS entry keeps its jitted name; the raw body stays callable
-# so the grouped executor can vmap it without nesting jits
 _solve_scan = partial(jax.jit, static_argnames=("n",))(_scan_single)
 
 
-@partial(jax.jit, static_argnames=("n",))
-def _solve_scan_grouped(row_ids, col_idx, vals, diag, accum, b_pad, n):
-    """Width-class grouped solve: every tensor carries a leading group
-    axis g — lane g runs the single-RHS scan on ITS OWN plan tensors
-    (``row_ids[g], col_idx[g], ...``) and rhs ``b_pad[g]``. The compiled
-    graph depends only on the stacked shapes ``(g, T, k, W, n)``, so one
-    XLA variant serves every combination of structurally-identical plans
-    (the serve layer's cross-pattern microbatching). Lanes are
-    data-independent: vmap batches the same op sequence per lane, so a
-    lane's bits never depend on what its neighbors hold (property-tested
-    in tests/test_serve_scaleout.py)."""
-    return jax.vmap(partial(_scan_single, n=n))(
-        row_ids, col_idx, vals, diag, accum, b_pad
+def _scan_lanes(row_ids, col_idx, vals, diag, accum, lane_idx, b_pad, n):
+    """Lane j runs the single-RHS scan on plan ``lane_idx[j]`` of the
+    stacked plan tensors (leading plan axis P) with rhs ``b_pad[j]``.
+
+    Each scan step slices step t out of every plan and gathers the
+    lanes' rows of that slice, so lanes that share a plan never copy its
+    tensors: a [lanes, T, k, W] copy of a 64^3 IC(0) plan at 32 lanes
+    asks a TPU v5e compile for 26 GB of HBM once the minor dimensions
+    are padded to the (8, 128) tile. Lanes are
+    data-independent: the vmapped step runs the same op sequence per
+    lane, so a lane's bits never depend on what its neighbors hold
+    (property-tested in tests/test_serve_scaleout.py)."""
+    k = row_ids.shape[2]
+    w = lane_idx.shape[0]
+    step = jax.vmap(_step_single)
+
+    def body(carry, t):
+        def lanes(a):
+            return jax.lax.dynamic_index_in_dim(a, t, 1, False)[lane_idx]
+
+        return step(
+            *carry, lanes(row_ids), lanes(col_idx), lanes(vals),
+            lanes(diag), lanes(accum), b_pad,
+        ), None
+
+    x0 = jnp.zeros((w, n + 1), b_pad.dtype)
+    acc0 = jnp.zeros((w, k), b_pad.dtype)
+    (x, _), _ = jax.lax.scan(
+        body, (x0, acc0), jnp.arange(row_ids.shape[1])
     )
+    return x[:, :n]
+
+
+_solve_scan_lanes = partial(jax.jit, static_argnames=("n",))(_scan_lanes)
 
 
 def solve_with_plan_group(pas, b_cols: jax.Array) -> jax.Array:
     """Solve lane j of ``b_cols`` f[g, n] (already in plan row order)
-    against ``pas[j]`` — one vmapped traversal over the whole group. All
-    plans must share the same tensor shapes (one width class); returns
+    against ``pas[j]`` — one traversal over the whole group. All plans
+    must share the same tensor shapes (one width class); returns
     x f[g, n].
 
-    Stacks the plan tensors per call — fine for replay/verification; the
-    serving hot path amortizes the stacking through a ``BankTensors``
-    bank + ``_solve_scan_banked`` instead (bitwise-identical output,
-    asserted in tests/test_serve_scaleout.py)."""
+    Stacks each distinct plan once per call — fine for
+    replay/verification; the serving hot path amortizes the stacking
+    through a ``BankTensors`` bank + ``_solve_scan_banked`` instead
+    (bitwise-identical output, asserted in
+    tests/test_serve_scaleout.py)."""
     dtype = pas[0].vals.dtype
     b = jnp.asarray(b_cols, dtype)
     b_pad = jnp.concatenate([b, jnp.zeros((b.shape[0], 1), dtype)], axis=1)
+    first = {}
+    lane_idx = np.array(
+        [first.setdefault(id(pa), len(first)) for pa in pas], np.int32
+    )
+    uniq = list({id(pa): pa for pa in pas}.values())
     stacked = [
-        jnp.stack([getattr(pa, f) for pa in pas])
+        jnp.stack([getattr(pa, f) for pa in uniq])
         for f in ("row_ids", "col_idx", "vals", "diag", "accum")
     ]
-    return _solve_scan_grouped(*stacked, b_pad, pas[0].n)
+    return _solve_scan_lanes(
+        *stacked, jnp.asarray(lane_idx), b_pad, pas[0].n
+    )
 
 
 class BankTensors(NamedTuple):
@@ -192,19 +218,15 @@ def _solve_scan_banked(
     ``lane_idx[j]`` — plan tensors AND its row permutation — solves, and
     un-permutes, all inside one compiled call. ``B`` is f[n, m] in
     caller row order; returns x f[n, m]. Bitwise-identical to
-    ``_solve_scan_grouped`` on the same lanes: the lane gathers and
-    permutations move bits unchanged, and the scan body is the same
-    vmapped ``_scan_single``."""
-    r = row_ids[lane_idx]
-    c = col_idx[lane_idx]
-    v = vals[lane_idx]
-    d = diag[lane_idx]
-    a = accum[lane_idx]
-    b = jnp.take_along_axis(B.T.astype(v.dtype), perm[lane_idx], axis=1)
+    ``solve_with_plan_group`` on the same lanes: the permutations move
+    bits unchanged, and both run ``_scan_lanes``."""
+    b = jnp.take_along_axis(
+        B.T.astype(vals.dtype), perm[lane_idx], axis=1
+    )
     b_pad = jnp.concatenate(
         [b, jnp.zeros((b.shape[0], 1), b.dtype)], axis=1
     )
-    x = jax.vmap(partial(_scan_single, n=n))(r, c, v, d, a, b_pad)
+    x = _scan_lanes(row_ids, col_idx, vals, diag, accum, lane_idx, b_pad, n)
     return jnp.take_along_axis(x, inv[lane_idx], axis=1).T
 
 

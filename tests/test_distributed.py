@@ -65,12 +65,13 @@ def test_train_step_lowers_on_multidevice_mesh():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.distributed.meshes import resolve_spec
+        from repro.launch.mesh import make_local_mesh
         from repro.models import abstract_params, logical_specs, param_specs
         from repro.train import AdamWConfig, make_train_step
         from repro.train.train_loop import TrainState
 
         cfg = get_reduced("deepseek_moe_16b")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_local_mesh(model=2, data=2)
         specs = param_specs(cfg)
         logical = logical_specs(specs)
         abst = abstract_params(specs, dtype=jnp.float32)
