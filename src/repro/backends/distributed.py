@@ -541,20 +541,6 @@ class DistributedBackend(Backend):
     def capabilities(self):
         return ("elastic", "shard-rows")
 
-    def bind(self, exec_plan, *, dtype=np.float32, steps_per_tile=8,
-             interpret=None, mesh=None, slack=0, shard="model"):
-        with obs.span(
-            "backend.bind",
-            cat="backend",
-            backend=self.name,
-            n=exec_plan.n,
-            slack=slack,
-            shard=shard,
-        ):
-            return self._bind(
-                exec_plan, dtype=dtype, mesh=mesh, slack=slack, shard=shard
-            )
-
     @staticmethod
     def _fused(exec_plan, slack):
         """The elastic certificate for ``slack`` (reuses the plan's
@@ -566,7 +552,8 @@ class DistributedBackend(Backend):
             ep = elastic_transform(exec_plan, slack)
         return ep
 
-    def _bind(self, exec_plan, *, dtype, mesh, slack, shard):
+    def bind(self, exec_plan, *, dtype=np.float32, steps_per_tile=8,
+             interpret=None, mesh=None, slack=0, shard="model"):
         if mesh is None:
             raise ValueError("backend='distributed' requires a mesh")
         if shard not in ("model", "rows"):

@@ -148,20 +148,6 @@ class PallasBackend(Backend):
                 f"backend='pallas' does not support shard={shard!r} "
                 "(no 'shard-rows' capability); use backend='distributed'"
             )
-        with obs.span(
-            "backend.bind",
-            cat="backend",
-            backend=self.name,
-            n=exec_plan.n,
-            slack=slack,
-        ):
-            return self._bind(
-                exec_plan, dtype=dtype, steps_per_tile=steps_per_tile,
-                interpret=interpret, slack=slack,
-            )
-
-    def _bind(self, exec_plan, *, dtype, steps_per_tile, interpret,
-              slack) -> BoundSolve:
         import jax
         import jax.numpy as jnp
 

@@ -249,16 +249,6 @@ class ScanBackend(Backend):
                 f"backend='scan' does not support shard={shard!r} "
                 "(no 'shard-rows' capability); use backend='distributed'"
             )
-        with obs.span(
-            "backend.bind",
-            cat="backend",
-            backend=self.name,
-            n=exec_plan.n,
-            slack=slack,
-        ):
-            return self._bind(exec_plan, dtype=dtype, slack=slack)
-
-    def _bind(self, exec_plan, *, dtype, slack) -> BoundSolve:
         import jax.numpy as jnp
 
         from repro.solver.executor import plan_arrays

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
+
 LANES = 128
 # Mosaic's default scoped-VMEM limit on v5e; the kernel asks for more
 # only when its resident x and b need it
@@ -218,6 +220,7 @@ def sptrsv_pallas(
     """Run the full scheduled solve; returns x shaped like ``b_pad`` (last
     row is scratch). A 2-D ``b_pad`` solves all m RHS in one pass per
     block of 128 columns."""
+    obs.counter_add("jit.trace.pallas")  # at trace time only
     T = row_ids.shape[0]
     assert T % steps_per_tile == 0, "pad T to a multiple of steps_per_tile"
     single = b_pad.ndim == 1

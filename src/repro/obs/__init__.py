@@ -9,11 +9,18 @@ runtime, across every layer of the stack:
     inspector   compile_plan phases, DAG build, schedule, reorder
     autotune    feature extraction, candidate scoring, measured trials
     cache       PlanCache hit/miss/evict/pin counters + lookup spans
-    backend     bind / update_values per backend
-    executor    per-solve dispatch; per-superstep (bulk) and
+    backend     bind (elastic transform and device puts included) /
+                update_values per backend
+    executor    per-solve dispatch (``executor.dispatch``: the enqueue,
+                not the device time); per-superstep (bulk) and
                 per-macro-step (elastic) device timings on a
-                ``timed=True`` plan
-    serve       microbatches, grouped batches, slot passes
+                ``timed=True`` plan; ``jit.trace.<program>`` counters,
+                bumped each time JAX traces a solve body
+    serve       one span per batch (microbatch, grouped batch, slot
+                pass) with its phases ``serve.batch.stack`` /
+                ``dispatch`` / ``wait`` / ``fulfil``, and one
+                ``serve.request`` record per request (submit -> done)
+    host        ``host.gc``: each garbage collection while enabled
 
 Usage::
 
@@ -30,9 +37,11 @@ site when off (no allocation — ``span()`` returns a process-wide
 singleton; bounded ~0.5% on the corpus hot path, enforced by
 ``benchmarks/obs_overhead.py``). Enabled tracing stays on the host side
 of the JAX async dispatch boundary, bounded <= 3% median solve latency
-on the same bench. ``jax.named_scope`` annotations inside the executors
-additionally tag the XLA HLO, so a ``jax.profiler`` trace carries
-plan-step names at zero runtime cost.
+on the same bench. Every enabled span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so a running JAX
+profiler records it on the clock of the device events; and
+``jax.named_scope`` annotations inside the executors tag the XLA HLO, so
+a ``jax.profiler`` trace carries plan-step names at zero runtime cost.
 """
 from repro.obs.export import (
     TRACE_SCHEMA,
@@ -51,6 +60,7 @@ from repro.obs.trace import (
     SpanRecord,
     TraceBuffer,
     active_buffer,
+    add_record,
     counter_add,
     disable,
     enable,
@@ -79,6 +89,7 @@ __all__ = [
     "TRACE_SCHEMA",
     "TraceBuffer",
     "active_buffer",
+    "add_record",
     "chrome_trace_events",
     "chrome_trace_payload",
     "counter_add",

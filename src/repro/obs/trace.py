@@ -14,6 +14,18 @@ Design constraints (the whole point of this module, enforced by
     plan-cache / serve / bank locks the instrumented code holds).
   * **bounded** — the buffer keeps at most ``cap`` spans and counts
     drops instead of growing without bound under a long serving run.
+  * **on the profiler's clock** — on the enabled path each span also
+    opens a ``jax.profiler.TraceAnnotation`` under its name (args stay
+    off the name), so a running JAX profiler stamps it on the clock of
+    the device events and an idle gap of the chip can be set against
+    what the program was doing. ``jax.profiler`` is imported by
+    ``enable()``, never by this module, so ``repro.obs`` imports
+    without JAX; the ``SpanRecord`` (perf-counter clock) is kept
+    either way for the Chrome exporter and ``SolveService.stats()``.
+  * **garbage-collector pauses as spans** — while enabled, a
+    ``gc.callbacks`` hook records each collection as a ``host.gc`` span
+    (args ``generation``, ``collected``) on both clocks; ``disable()``
+    unregisters it.
 
 Spans nest lexically (context managers), so per-thread begin/end pairs
 are properly bracketed by construction — exactly what the Chrome
@@ -26,6 +38,7 @@ JSON/float64 consumers); ``reset_counters`` zeroes them.
 """
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -60,7 +73,10 @@ class TraceBuffer:
     def __init__(self, name: str = "default", cap: int = DEFAULT_CAP):
         self.name = name
         self.cap = int(cap)
-        self._lock = threading.Lock()
+        # reentrant: a garbage collection can start inside a locked
+        # section (an allocation there) and its ``host.gc`` span then
+        # lands in the buffer from the same thread
+        self._lock = threading.RLock()
         self._spans: List[SpanRecord] = []
         self._counters: Dict[str, int] = {}
         self.dropped = 0  # spans discarded once cap was reached
@@ -166,23 +182,46 @@ def get_buffer(name: str = "default") -> TraceBuffer:
 # still lands in its buffer, which is the useful behavior.
 _ENABLED = False
 _ACTIVE: Optional[TraceBuffer] = None
+# jax.profiler.TraceAnnotation once enable() has looked for it; None
+# where JAX is not installed (spans then reach the buffer only)
+_ANNOTATION = None
+
+
+def _profiler_annotation():
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _set_state(enabled: bool, buf: Optional[TraceBuffer]) -> None:
+    """Switch tracing and the ``host.gc`` hook together (under
+    ``_REG_LOCK``)."""
+    global _ENABLED, _ACTIVE, _ANNOTATION
+    if enabled and _ANNOTATION is None:
+        _ANNOTATION = _profiler_annotation()
+    _ACTIVE = buf
+    _ENABLED = enabled
+    hooked = _on_gc in gc.callbacks
+    if enabled and not hooked:
+        gc.callbacks.append(_on_gc)
+    elif not enabled and hooked:
+        gc.callbacks.remove(_on_gc)
 
 
 def enable(buffer: Optional[TraceBuffer] = None) -> TraceBuffer:
     """Turn tracing on, recording into ``buffer`` (default: the global
     ``"default"`` buffer). Returns the active buffer."""
-    global _ENABLED, _ACTIVE
     buf = buffer if buffer is not None else get_buffer("default")
     with _REG_LOCK:
-        _ACTIVE = buf
-        _ENABLED = True
+        _set_state(True, buf)
     return buf
 
 
 def disable() -> None:
-    global _ENABLED
     with _REG_LOCK:
-        _ENABLED = False
+        _set_state(False, _ACTIVE)
 
 
 def is_enabled() -> bool:
@@ -199,7 +238,6 @@ def tracing(buffer: Optional[TraceBuffer] = None):
     """Scoped enable: ``with obs.tracing() as buf: ...`` — restores the
     previous on/off state (and active buffer) on exit, so tests and
     benchmarks can trace one region without leaking global state."""
-    global _ENABLED, _ACTIVE
     with _REG_LOCK:
         prev = (_ENABLED, _ACTIVE)
     buf = enable(buffer)
@@ -207,7 +245,7 @@ def tracing(buffer: Optional[TraceBuffer] = None):
         yield buf
     finally:
         with _REG_LOCK:
-            _ENABLED, _ACTIVE = prev
+            _set_state(*prev)
 
 
 # ----------------------------------------------------------------- spans
@@ -231,12 +269,31 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _record(buf, name, cat, t0_ns, t1_ns, args, tid=None, thread_name=None):
+    if tid is None:
+        cur = threading.current_thread()
+        tid, thread_name = cur.ident or 0, cur.name
+    buf.add_span(
+        SpanRecord(
+            name=name,
+            cat=cat,
+            tid=tid,
+            thread_name=thread_name,
+            t0_ns=t0_ns,
+            t1_ns=t1_ns,
+            args=args,
+        )
+    )
+
+
 class Span:
     """A live span: created by ``span()`` on the enabled path, recorded
-    into its buffer on ``__exit__``. ``set(key=value)`` attaches args
-    discovered mid-span (e.g. a cache hit flag known only at the end)."""
+    into its buffer on ``__exit__`` and, where JAX is installed, also a
+    ``jax.profiler.TraceAnnotation`` of the same name while it is open.
+    ``set(key=value)`` attaches args discovered mid-span (e.g. a cache
+    hit flag known only at the end)."""
 
-    __slots__ = ("name", "cat", "args", "_buf", "_t0")
+    __slots__ = ("name", "cat", "args", "_buf", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: dict, buf: TraceBuffer):
         self.name = name
@@ -244,31 +301,27 @@ class Span:
         self.args = args
         self._buf = buf
         self._t0 = 0
+        self._ann = None
 
     def set(self, **args) -> "Span":
         self.args.update(args)
         return self
 
     def __enter__(self) -> "Span":
+        if _ANNOTATION is not None:
+            self._ann = _ANNOTATION(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        cur = threading.current_thread()
-        self._buf.add_span(
-            SpanRecord(
-                name=self.name,
-                cat=self.cat,
-                tid=cur.ident or 0,
-                thread_name=cur.name,
-                t0_ns=self._t0,
-                t1_ns=t1,
-                args=self.args,
-            )
-        )
+        _record(self._buf, self.name, self.cat, self._t0, t1, self.args)
         return False
 
 
@@ -289,6 +342,63 @@ def span(name: str, cat: str = "", **args):
     if buf is None:  # disable() raced us; drop silently
         return NULL_SPAN
     return Span(name, cat or name.split(".", 1)[0], args, buf)
+
+
+def add_record(
+    name: str,
+    t0_s: float,
+    t1_s: float,
+    *,
+    cat: str = "",
+    thread_name: str = "",
+    **args,
+) -> None:
+    """Record a span that has already finished, from two
+    ``time.perf_counter()`` readings: for an interval that no one thread
+    brackets, such as a request from submit (client thread) to done
+    (worker thread). It reaches the buffer only, not the profiler, under
+    a pseudo-thread of its own (``tid`` 0, ``thread_name``) so that the
+    exporter's per-thread nesting stays intact. A no-op (one flag check)
+    while tracing is off."""
+    if not _ENABLED:
+        return
+    buf = _ACTIVE
+    if buf is not None:
+        _record(
+            buf, name, cat or name.split(".", 1)[0],
+            int(t0_s * 1e9), int(t1_s * 1e9), args,
+            tid=0, thread_name=thread_name or name,
+        )
+
+
+class _GcPause:
+    """The collection in progress (collections never nest, and one runs
+    at a time in the process), so one slot holds its start."""
+
+    t0 = 0
+    ann = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook, registered only while tracing is on."""
+    if phase == "start":
+        if _ANNOTATION is not None:
+            _GcPause.ann = _ANNOTATION("host.gc")
+            _GcPause.ann.__enter__()
+        _GcPause.t0 = time.perf_counter_ns()
+        return
+    t1 = time.perf_counter_ns()
+    ann, _GcPause.ann = _GcPause.ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    buf = _ACTIVE
+    if _ENABLED and buf is not None and _GcPause.t0:
+        _record(
+            buf, "host.gc", "host", _GcPause.t0, t1,
+            {"generation": info.get("generation"),
+             "collected": info.get("collected")},
+        )
+    _GcPause.t0 = 0
 
 
 def counter_add(name: str, value: int = 1) -> None:

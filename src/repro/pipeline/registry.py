@@ -115,16 +115,17 @@ def schedule(
         o = o.replace(k=k)
     if opts:
         o = o.replace(**opts)
-    if strategy == "auto":
-        from repro.autotune.selector import select_schedule
-
-        return select_schedule(dag, o)[1]
     with obs.span(
-        f"inspector.schedule.{strategy}",
+        "inspector.schedule",
         cat="inspector",
+        strategy=strategy,
         n=dag.n,
         k=o.k,
     ):
+        if strategy == "auto":
+            from repro.autotune.selector import select_schedule
+
+            return select_schedule(dag, o)[1]
         return get_scheduler(strategy)(dag, o)
 
 

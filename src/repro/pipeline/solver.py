@@ -166,29 +166,48 @@ class TriangularSolver:
         self._source_data: Optional[np.ndarray] = None  # set by plan()
         self._selection = None  # autotune Selection, set by plan(auto)
         self.plan_key = None  # concrete plan-cache key, set by plan()
-        total_inv = np.empty_like(total_perm)
-        total_inv[total_perm] = np.arange(len(total_perm))
-        self._perm = jnp.asarray(total_perm, jnp.int32)
-        self._inv = jnp.asarray(total_inv, jnp.int32)
-        self._bind()
+        self._bind(total_perm)
 
     # ---------------------------------------------------------- binding
-    def _bind(self) -> None:
+    def _bind(self, total_perm: np.ndarray) -> None:
         """Bind device-resident plan tensors through the
         ``repro.backends`` registry — called once at construction.
         Numeric refreshes never come back here: they go through the
-        bound solve's device-side ``update_values`` gather."""
+        bound solve's device-side ``update_values`` gather. The
+        ``backend.bind`` span covers all of it: the elastic certificate
+        (when ``plan()`` has not attached it for the verifier), the row
+        permutations' and the backend's device puts."""
         from repro.backends import get_backend
 
-        self._bound = get_backend(self.backend).bind(
-            self.exec_plan,
-            dtype=self.dtype,
-            steps_per_tile=self._steps_per_tile,
-            interpret=self._interpret,
-            mesh=self._mesh,
+        plan = self.exec_plan
+        with obs.span(
+            "backend.bind",
+            cat="backend",
+            backend=self.backend,
+            n=plan.n,
             slack=self._slack,
             shard=self._shard,
-        )
+        ):
+            if self._slack > 0 and (
+                plan.elastic is None or plan.elastic.slack != self._slack
+            ):
+                from repro.core import elastic_transform
+
+                # attached, so that ExecPlan.stats() reads the same one
+                plan.elastic = elastic_transform(plan, self._slack)
+            total_inv = np.empty_like(total_perm)
+            total_inv[total_perm] = np.arange(len(total_perm))
+            self._perm = jnp.asarray(total_perm, jnp.int32)
+            self._inv = jnp.asarray(total_inv, jnp.int32)
+            self._bound = get_backend(self.backend).bind(
+                plan,
+                dtype=self.dtype,
+                steps_per_tile=self._steps_per_tile,
+                interpret=self._interpret,
+                mesh=self._mesh,
+                slack=self._slack,
+                shard=self._shard,
+            )
 
     @property
     def bound(self):
@@ -250,7 +269,8 @@ class TriangularSolver:
         if self.timed:
             return self.solve_timed(b)[0]
         b = self._check_b(b)
-        with obs.span("executor.solve", cat="executor", n=self.n):
+        # the enqueue: JAX returns before the device has finished
+        with obs.span("executor.dispatch", cat="executor", n=self.n):
             x = self._bound.solve(b[self._perm])
             return x[self._inv]
 
@@ -263,7 +283,7 @@ class TriangularSolver:
         trace buffer when tracing is enabled."""
         b = self._check_b(b)
         with obs.span(
-            "executor.solve", cat="executor", n=self.n, timed=True
+            "executor.dispatch", cat="executor", n=self.n, timed=True
         ):
             x, steps = self._bound.solve_timed(b[self._perm])
             x = x[self._inv]
@@ -505,20 +525,26 @@ class TriangularSolver:
         if strategy == "auto" and sched is None:
             from repro.autotune.selector import resolve_auto_full
 
-            selection, pre_sched, pre_solver = resolve_auto_full(
-                a,
-                options=o,
-                lower=lower,
-                tune=tune,
-                cache=cache,
-                fp=fp,
-                allow_elastic=elastic_ok,
-                plan_kwargs=dict(
-                    backend=backend, dtype=dtype, width=width,
-                    mesh=mesh, steps_per_tile=steps_per_tile,
-                    interpret=interpret, shard=shard,
-                ),
-            )
+            # the DAG, features and scoring: scheduling under "auto"
+            with obs.span(
+                "inspector.schedule", cat="inspector", strategy="auto",
+                n=a.n_rows,
+            ) as sp:
+                selection, pre_sched, pre_solver = resolve_auto_full(
+                    a,
+                    options=o,
+                    lower=lower,
+                    tune=tune,
+                    cache=cache,
+                    fp=fp,
+                    allow_elastic=elastic_ok,
+                    plan_kwargs=dict(
+                        backend=backend, dtype=dtype, width=width,
+                        mesh=mesh, steps_per_tile=steps_per_tile,
+                        interpret=interpret, shard=shard,
+                    ),
+                )
+                sp.set(picked=selection.strategy)
             strategy, o = selection.strategy, selection.options
         # o (a frozen dataclass) covers every scheduling knob incl. k,
         # reorder and the elastic slack; binding params (mesh identity,
@@ -543,8 +569,8 @@ class TriangularSolver:
                 with obs.span("inspector.dag", cat="inspector", n=n):
                     dag = dag_from_lower_csr(m0)
                 with obs.span(
-                    f"inspector.schedule.{strategy}", cat="inspector",
-                    n=n, k=o.k,
+                    "inspector.schedule", cat="inspector",
+                    strategy=strategy, n=n, k=o.k,
                 ):
                     s = get_scheduler(strategy)(dag, o)
             if o.reorder:
@@ -555,14 +581,14 @@ class TriangularSolver:
                 m2, s2, inner = m0, s, np.arange(n, dtype=np.int64)
 
             plan = compile_plan(m2, s2, width=width, dtype=np.dtype(dtype))
-            if o.slack > 0:
-                # attach the slack certificate so the backend bind (and
-                # ExecPlan.stats barrier accounting) reuse one transform
-                from repro.core import elastic_transform
-
-                plan.elastic = elastic_transform(plan, o.slack)
-
             if check_level != "off":
+                if o.slack > 0:
+                    # the verifier audits the slack certificate, so it is
+                    # made here; the bind reuses it (else the bind makes
+                    # and attaches it)
+                    from repro.core import elastic_transform
+
+                    plan.elastic = elastic_transform(plan, o.slack)
                 # verify against m2 BEFORE the val_src rebase below —
                 # the provenance audit matches sources against the
                 # matrix the plan was actually compiled from

@@ -44,6 +44,7 @@ bounds) transparently fall back to the microbatch path.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import threading
 import time
 from collections import Counter
@@ -69,18 +70,24 @@ class SolveTicket:
     """Future for one submitted request. ``result()`` blocks until the
     microbatch containing this request has been served — or raises
     immediately if the request was ``rejected`` at admission
-    (back-pressure)."""
+    (back-pressure). ``request_id`` numbers the service's requests in
+    submit order; ``batch`` is the id of the batch (or slot pass) that
+    served it."""
 
     __slots__ = (
-        "fingerprint", "version", "batch_width", "batch_position",
-        "served_by", "rejected", "_event", "_result", "_error",
-        "t_submit", "t_admit", "t_done",
+        "fingerprint", "version", "request_id", "batch", "batch_width",
+        "batch_position", "served_by", "rejected", "_event", "_result",
+        "_error", "t_submit", "t_dispatch", "t_admit", "t_done",
     )
 
-    def __init__(self, fingerprint: str, version: int):
+    def __init__(
+        self, fingerprint: str, version: int, request_id: int = -1
+    ):
         self.fingerprint = fingerprint
         self.version = version  # plan version pinned at admission
+        self.request_id = request_id
         self.rejected = False  # True: bounced by the admission bound
+        self.batch: Optional[int] = None  # set at dispatch
         self.batch_width: Optional[int] = None  # set at dispatch
         self.batch_position: Optional[int] = None  # column in the batch
         # the TriangularSolver that served this request — kept on the
@@ -91,6 +98,8 @@ class SolveTicket:
         self._result = None
         self._error: Optional[BaseException] = None
         self.t_submit = time.perf_counter()
+        # taken off the queue (the batch's start; continuous: the pass's)
+        self.t_dispatch: Optional[float] = None
         self.t_admit: Optional[float] = None  # continuous: lane insertion
         self.t_done: Optional[float] = None
 
@@ -136,6 +145,16 @@ class _Request:
     def __init__(self, ticket: SolveTicket, b: np.ndarray):
         self.ticket = ticket
         self.b = b
+
+
+def record_requests(tickets) -> None:
+    """One ``serve.request`` record per fulfilled ticket (tracing on)."""
+    for t in tickets:
+        obs.add_record(
+            "serve.request", t.t_submit, t.t_done, cat="serve",
+            id=t.request_id, batch=t.batch,
+            queue_s=t.t_dispatch - t.t_submit,
+        )
 
 
 class GroupReplay:
@@ -293,6 +312,10 @@ class SolveService:
             max_batch=self.max_batch, max_wait_us=max_wait_us
         )
         self.metrics = ServeMetrics()
+        # ids in submit / dispatch order (itertools.count's next() is
+        # atomic under the GIL, so producers and workers share them)
+        self._request_ids = itertools.count()
+        self._batch_ids = itertools.count()
         self._closed = False
         self._workers = [
             threading.Thread(
@@ -405,6 +428,7 @@ class SolveService:
                 eng = self._engines[wc] = SlotEngine(
                     n_slots=self.n_slots,
                     metrics=self.metrics,
+                    batch_ids=self._batch_ids,
                     is_live=self._key_live,
                     on_complete=self._key_complete,
                     name=_width_class_label(wc),
@@ -468,7 +492,7 @@ class SolveService:
         if self.max_queue is not None:
             depth = self._backlog()
             if depth >= self.max_queue:
-                ticket = SolveTicket(fp, -1)
+                ticket = SolveTicket(fp, -1, next(self._request_ids))
                 self.metrics.record_rejected(fp)
                 ticket._reject(depth, self.max_queue)
                 return ticket
@@ -478,7 +502,7 @@ class SolveService:
         # banked twin) fall back to the microbatch path below.
         if self.mode == "continuous" and vp.groupable:
             version, solver = vp.admit()
-            ticket = SolveTicket(fp, version)
+            ticket = SolveTicket(fp, version, next(self._request_ids))
             self.metrics.record_submit(fp)
             try:
                 self._dispatcher.submit(
@@ -493,7 +517,7 @@ class SolveService:
                 raise
             return ticket
         version, _ = vp.admit()
-        ticket = SolveTicket(fp, version)
+        ticket = SolveTicket(fp, version, next(self._request_ids))
         self.metrics.record_submit(fp)
         # width-class routing coalesces structurally-identical plans into
         # one grouped dispatch; each request still pins (and is served
@@ -566,13 +590,10 @@ class SolveService:
             w = -(-w // self._batch_align) * self._batch_align
         return w
 
-    def _serve_plain(self, fp: str, version: int, reqs) -> None:
-        """One (pattern, version) microbatch — the classic multi-RHS
-        path; every column shares one solver."""
-        vp = self._patterns[fp]
-        t0 = time.perf_counter()
-        try:
-            solver = vp.solver_for(version)
+    def _stack(self, reqs, batch: int):
+        """``serve.batch.stack``: the requests' right-hand sides as the
+        columns of B, zero-padded to the dispatch width ``w``."""
+        with obs.span("serve.batch.stack", cat="serve", batch=batch):
             m = len(reqs)
             B = np.stack([r.b for r in reqs], axis=1)
             w = self._dispatch_width(m)
@@ -580,23 +601,58 @@ class SolveService:
                 B = np.concatenate(
                     [B, np.zeros((B.shape[0], w - m), B.dtype)], axis=1
                 )
+        return B, w
+
+    @staticmethod
+    def _dispatch_and_wait(batch: int, solve, *args):
+        """``serve.batch.dispatch``: ``solve(*args)`` up to its return
+        (the host permutation and the transfer); ``serve.batch.wait``:
+        the device and the readback."""
+        with obs.span("serve.batch.dispatch", cat="serve", batch=batch):
+            X = solve(*args)
+        with obs.span("serve.batch.wait", cat="serve", batch=batch):
+            return np.asarray(X)
+
+    @staticmethod
+    def _fulfil(reqs, X, batch: int, width: int, t0: float, served_by):
+        """Column j of ``X`` to request j, served by ``served_by[j]``."""
+        for j, r in enumerate(reqs):
+            t = r.ticket
+            t.batch = batch
+            t.t_dispatch = t0
+            t.batch_width = width
+            t.batch_position = j
+            t.served_by = served_by[j]
+            t._fulfill(np.ascontiguousarray(X[:, j]))
+
+    def _serve_plain(self, fp: str, version: int, reqs) -> None:
+        """One (pattern, version) microbatch — the classic multi-RHS
+        path; every column shares one solver. The ``serve.microbatch``
+        span covers the batch from pop to its last ticket fulfilled."""
+        vp = self._patterns[fp]
+        t0 = time.perf_counter()
+        batch = next(self._batch_ids)
+        try:
             with obs.span(
-                "serve.microbatch", cat="serve", size=m, width=w
-            ):
-                X = np.asarray(solver.solve(B))
-            t1 = time.perf_counter()
-            for j, r in enumerate(reqs):
-                r.ticket.batch_width = w
-                r.ticket.batch_position = j
-                r.ticket.served_by = solver
-                r.ticket._fulfill(np.ascontiguousarray(X[:, j]))
-            self.metrics.record_batch(
-                fp,
-                m,
-                queue_waits=[t0 - r.ticket.t_submit for r in reqs],
-                e2e=[r.ticket.t_done - r.ticket.t_submit for r in reqs],
-                solve_seconds=t1 - t0,
-            )
+                "serve.microbatch", cat="serve", batch=batch, size=len(reqs)
+            ) as sp:
+                solver = vp.solver_for(version)
+                B, w = self._stack(reqs, batch)
+                sp.set(width=w)
+                X = self._dispatch_and_wait(batch, solver.solve, B)
+                t1 = time.perf_counter()
+                with obs.span("serve.batch.fulfil", cat="serve", batch=batch):
+                    self._fulfil(reqs, X, batch, w, t0, [solver] * len(reqs))
+                    tickets = [r.ticket for r in reqs]
+                    self.metrics.record_batch(
+                        fp,
+                        len(reqs),
+                        queue_waits=[t0 - t.t_submit for t in tickets],
+                        e2e=[t.t_done - t.t_submit for t in tickets],
+                        solve_seconds=t1 - t0,
+                    )
+                    if obs.is_enabled():
+                        record_requests(tickets)
         except Exception as e:  # scatter the failure, keep serving
             for r in reqs:
                 r.ticket._fulfill(None, e)
@@ -619,55 +675,52 @@ class SolveService:
             self._serve_plain(fp, version, reqs)
             return
         t0 = time.perf_counter()
+        batch = next(self._batch_ids)
         try:
-            solvers = [
-                self._patterns[fp].solver_for(version)
-                for fp, version in req_keys
-            ]
-            bank = self._banks.setdefault(wc, GroupBank())
-            for key, solver in zip(req_keys, solvers):
-                bank.add(key, solver)
-            # retire bank lanes of drained, superseded versions (their
-            # VersionedPlans entry is gone, so they can never dispatch).
-            # Liveness is queried INSIDE the prune (under the bank lock,
-            # serialized with concurrent adds) — a hoisted snapshot could
-            # go stale against another worker's just-added lane and drop
-            # it: any in-flight batch pins its versions, so a
-            # query-at-prune-time can never see them as dead.
-            fps_touched = {fp for fp, _ in req_keys}
-            bank.prune(
-                lambda k: k[0] not in fps_touched
-                or k[1] in self._patterns[k[0]].live_versions()
-            )
-            m = len(reqs)
-            w = self._dispatch_width(m)
-            B = np.stack([r.b for r in reqs], axis=1)
-            keys = list(req_keys)
-            if w > m:
-                B = np.concatenate(
-                    [B, np.zeros((B.shape[0], w - m), B.dtype)], axis=1
-                )
-                keys = keys + [keys[0]] * (w - m)  # padding lanes
             with obs.span(
-                "serve.grouped_batch",
-                cat="serve",
-                size=m,
-                width=w,
-                patterns=len(fps_touched),
-            ):
-                X = np.asarray(bank.solve(keys, B))
-            t1 = time.perf_counter()
-            for j, r in enumerate(reqs):
-                r.ticket.batch_width = w
-                r.ticket.batch_position = j
-                r.ticket.served_by = GroupReplay(solvers[j])
-                r.ticket._fulfill(np.ascontiguousarray(X[:, j]))
-            self.metrics.record_grouped_batch(
-                [r.ticket.fingerprint for r in reqs],
-                queue_waits=[t0 - r.ticket.t_submit for r in reqs],
-                e2e=[r.ticket.t_done - r.ticket.t_submit for r in reqs],
-                solve_seconds=t1 - t0,
-            )
+                "serve.grouped_batch", cat="serve", batch=batch,
+                size=len(reqs),
+            ) as sp:
+                solvers = [
+                    self._patterns[fp].solver_for(version)
+                    for fp, version in req_keys
+                ]
+                bank = self._banks.setdefault(wc, GroupBank())
+                for key, solver in zip(req_keys, solvers):
+                    bank.add(key, solver)
+                # retire bank lanes of drained, superseded versions
+                # (their VersionedPlans entry is gone, so they can never
+                # dispatch). Liveness is queried INSIDE the prune (under
+                # the bank lock, serialized with concurrent adds) — a
+                # hoisted snapshot could go stale against another
+                # worker's just-added lane and drop it: any in-flight
+                # batch pins its versions, so a query-at-prune-time can
+                # never see them as dead.
+                fps_touched = {fp for fp, _ in req_keys}
+                bank.prune(
+                    lambda k: k[0] not in fps_touched
+                    or k[1] in self._patterns[k[0]].live_versions()
+                )
+                B, w = self._stack(reqs, batch)
+                sp.set(width=w, patterns=len(fps_touched))
+                # padding lanes solve against the first request's plan
+                keys = req_keys + [req_keys[0]] * (w - len(reqs))
+                X = self._dispatch_and_wait(batch, bank.solve, keys, B)
+                t1 = time.perf_counter()
+                with obs.span("serve.batch.fulfil", cat="serve", batch=batch):
+                    self._fulfil(
+                        reqs, X, batch, w, t0,
+                        [GroupReplay(s) for s in solvers],
+                    )
+                    tickets = [r.ticket for r in reqs]
+                    self.metrics.record_grouped_batch(
+                        [t.fingerprint for t in tickets],
+                        queue_waits=[t0 - t.t_submit for t in tickets],
+                        e2e=[t.t_done - t.t_submit for t in tickets],
+                        solve_seconds=t1 - t0,
+                    )
+                    if obs.is_enabled():
+                        record_requests(tickets)
         except Exception as e:  # scatter the failure, keep serving
             for r in reqs:
                 r.ticket._fulfill(None, e)

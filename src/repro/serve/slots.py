@@ -61,10 +61,11 @@ admit/complete/evict sequences and audit its invariants directly.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import Counter, deque
-from typing import Callable, Dict, Hashable, List, Optional
+from typing import Callable, Dict, Hashable, Iterator, List, Optional
 
 import numpy as np
 
@@ -218,6 +219,7 @@ class SlotEngine:
         is_live: Optional[Callable[[Hashable], bool]] = None,
         on_complete: Optional[Callable[[Hashable, int], None]] = None,
         name: str = "slots",
+        batch_ids: Optional[Iterator[int]] = None,
     ):
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
@@ -231,6 +233,10 @@ class SlotEngine:
         self._is_live = is_live if is_live is not None else (lambda k: True)
         self._on_complete = (
             on_complete if on_complete is not None else (lambda k, c: None)
+        )
+        # pass ids, shared with the service's batch ids when it owns us
+        self._batch_ids = (
+            batch_ids if batch_ids is not None else itertools.count()
         )
         self.passes = 0  # dispatch passes actually executed
         self.occupancy_hist: Counter = Counter()  # occupancy -> passes
@@ -248,93 +254,104 @@ class SlotEngine:
             )
 
     def _run_pass(self, reqs: List[SlotRequest]) -> None:
-        # lazy import: service.py imports this module at load time
-        from repro.serve.service import GroupReplay
+        """One dispatch pass: the ``serve.slot_pass`` span covers it from
+        admission to the last ticket fulfilled, with the same phases as
+        a microbatch (``serve.batch.stack``: lane inserts;
+        ``dispatch``; ``wait``: the lanes' readback; ``fulfil``)."""
+        batch = next(self._batch_ids)
+        with obs.span("serve.slot_pass", cat="serve", batch=batch) as sp:
+            # lazy import: service.py imports this module at load time
+            from repro.serve.service import GroupReplay, record_requests
 
-        admitted = []
-        for r in reqs:
+            admitted = []
+            for r in reqs:
+                try:
+                    self._ensure_device(r.solver)
+                    self.bank.add(r.key, r.solver)
+                    lane = self.state.admit(r.ticket)
+                except Exception as e:
+                    r.ticket._fulfill(None, e)
+                    self.metrics.record_failure(r.ticket.fingerprint, 1)
+                    self._on_complete(r.key, 1)
+                    continue
+                admitted.append((lane, r))
+            if not admitted:
+                return
+            t0 = time.perf_counter()
+            cls, B = self._cls, self._B
+            with obs.span("serve.batch.stack", cat="serve", batch=batch):
+                for lane, r in admitted:
+                    B = cls.insert_lane(B, lane, np.asarray(r.b, self._dtype))
+                    r.ticket.t_admit = time.perf_counter()
+            self._B = B
+            occupied = {lane: r for lane, r in admitted}
+            # dispatch the smallest pow2 lane prefix covering the occupants
+            # (lanes allocate lowest-first, so the prefix is tight): a
+            # lightly-loaded bank solves at width 2, not n_slots
+            width = pad_width(max(occupied) + 1, self.n_slots)
+            sp.set(width=width, occupied=len(occupied))
+            # free lanes inside the prefix solve their stale columns against
+            # a filler plan — discarded results; lane independence keeps
+            # them from ever touching an occupied lane's bits
+            filler = admitted[0][1].key
+            keys = [
+                occupied[lane].key if lane in occupied else filler
+                for lane in range(width)
+            ]
             try:
-                self._ensure_device(r.solver)
-                self.bank.add(r.key, r.solver)
-                lane = self.state.admit(r.ticket)
-            except Exception as e:
-                r.ticket._fulfill(None, e)
-                self.metrics.record_failure(r.ticket.fingerprint, 1)
-                self._on_complete(r.key, 1)
-                continue
-            admitted.append((lane, r))
-        if not admitted:
-            return
-        t0 = time.perf_counter()
-        cls, B = self._cls, self._B
-        for lane, r in admitted:
-            B = cls.insert_lane(B, lane, np.asarray(r.b, self._dtype))
-            r.ticket.t_admit = time.perf_counter()
-        self._B = B
-        occupied = {lane: r for lane, r in admitted}
-        # dispatch the smallest pow2 lane prefix covering the occupants
-        # (lanes allocate lowest-first, so the prefix is tight): a
-        # lightly-loaded bank solves at width 2, not n_slots
-        width = pad_width(max(occupied) + 1, self.n_slots)
-        # free lanes inside the prefix solve their stale columns against
-        # a filler plan — discarded results; lane independence keeps
-        # them from ever touching an occupied lane's bits
-        filler = admitted[0][1].key
-        keys = [
-            occupied[lane].key if lane in occupied else filler
-            for lane in range(width)
-        ]
-        try:
-            with obs.span(
-                "serve.slot_pass",
-                cat="serve",
-                width=width,
-                occupied=len(occupied),
-            ):
-                X = self.bank.solve_resident(keys, B)
-            xs = {
-                lane: np.asarray(cls.extract_lane(X, lane))
-                for lane in occupied
-            }
-        except Exception as e:  # scatter the failure, keep serving
-            for lane, r in occupied.items():
-                self.state.evict(lane)
-                r.ticket._fulfill(None, e)
-            for fp, cnt in Counter(
-                r.ticket.fingerprint for r in occupied.values()
-            ).items():
-                self.metrics.record_failure(fp, cnt)
-            for key, cnt in Counter(
-                r.key for r in occupied.values()
-            ).items():
+                with obs.span(
+                    "serve.batch.dispatch", cat="serve", batch=batch
+                ):
+                    X = self.bank.solve_resident(keys, B)
+                with obs.span("serve.batch.wait", cat="serve", batch=batch):
+                    xs = {
+                        lane: np.asarray(cls.extract_lane(X, lane))
+                        for lane in occupied
+                    }
+            except Exception as e:  # scatter the failure, keep serving
+                for lane, r in occupied.items():
+                    self.state.evict(lane)
+                    r.ticket._fulfill(None, e)
+                for fp, cnt in Counter(
+                    r.ticket.fingerprint for r in occupied.values()
+                ).items():
+                    self.metrics.record_failure(fp, cnt)
+                for key, cnt in Counter(
+                    r.key for r in occupied.values()
+                ).items():
+                    self._on_complete(key, cnt)
+                return
+            t1 = time.perf_counter()
+            with obs.span("serve.batch.fulfil", cat="serve", batch=batch):
+                for lane, r in occupied.items():
+                    t = r.ticket
+                    t.batch = batch
+                    t.t_dispatch = t0
+                    t.batch_width = width
+                    t.batch_position = lane
+                    t.served_by = GroupReplay(r.solver)
+                    t._fulfill(np.ascontiguousarray(xs[lane]))
+                    self.state.release(lane)
+                self.passes += 1
+                self.occupancy_hist[len(occupied)] += 1
+                tickets = [r.ticket for r in occupied.values()]
+                self.metrics.record_slot_pass(
+                    [t.fingerprint for t in tickets],
+                    queue_waits=[t.t_admit - t.t_submit for t in tickets],
+                    slot_times=[t.t_done - t.t_admit for t in tickets],
+                    e2e=[t.t_done - t.t_submit for t in tickets],
+                    solve_seconds=t1 - t0,
+                    occupancy=len(occupied),
+                    n_slots=self.n_slots,
+                )
+                if obs.is_enabled():
+                    record_requests(tickets)
+            for key, cnt in Counter(r.key for r in occupied.values()).items():
                 self._on_complete(key, cnt)
-            return
-        t1 = time.perf_counter()
-        for lane, r in occupied.items():
-            t = r.ticket
-            t.batch_width = width
-            t.batch_position = lane
-            t.served_by = GroupReplay(r.solver)
-            t._fulfill(np.ascontiguousarray(xs[lane]))
-            self.state.release(lane)
-        self.passes += 1
-        self.occupancy_hist[len(occupied)] += 1
-        tickets = [r.ticket for r in occupied.values()]
-        self.metrics.record_slot_pass(
-            [t.fingerprint for t in tickets],
-            queue_waits=[t.t_admit - t.t_submit for t in tickets],
-            slot_times=[t.t_done - t.t_admit for t in tickets],
-            e2e=[t.t_done - t.t_submit for t in tickets],
-            solve_seconds=t1 - t0,
-            occupancy=len(occupied),
-            n_slots=self.n_slots,
-        )
-        for key, cnt in Counter(r.key for r in occupied.values()).items():
-            self._on_complete(key, cnt)
-        # retire bank lanes of drained, superseded versions — queried
-        # per key at prune time (under the bank lock): any key with a
-        # queued or in-lane request is pinned, hence still live
-        self.bank.prune(self._is_live)
+            # retire bank lanes of drained, superseded versions — queried
+            # per key at prune time (under the bank lock): any key with a
+            # queued or in-lane request is pinned, hence still live
+            self.bank.prune(self._is_live)
 
     # ------------------------------------------------------------- warm-up
     def warm(self, key, solver) -> None:

@@ -96,6 +96,7 @@ def _step_single(x, acc, rows, cols, v, d, a, b_pad):
 
 
 def _scan_single(row_ids, col_idx, vals, diag, accum, b_pad, n):
+    obs.counter_add("jit.trace.scan")  # at trace time only
     x0 = jnp.zeros(n + 1, dtype=b_pad.dtype)
     acc0 = jnp.zeros(row_ids.shape[1], dtype=b_pad.dtype)
 
@@ -123,6 +124,7 @@ def _scan_lanes(row_ids, col_idx, vals, diag, accum, lane_idx, b_pad, n):
     data-independent: the vmapped step runs the same op sequence per
     lane, so a lane's bits never depend on what its neighbors hold
     (property-tested in tests/test_serve_scaleout.py)."""
+    obs.counter_add("jit.trace.scan_lanes")  # at trace time only
     k = row_ids.shape[2]
     w = lane_idx.shape[0]
     step = jax.vmap(_step_single)
@@ -220,6 +222,7 @@ def _solve_scan_banked(
     caller row order; returns x f[n, m]. Bitwise-identical to
     ``solve_with_plan_group`` on the same lanes: the permutations move
     bits unchanged, and both run ``_scan_lanes``."""
+    obs.counter_add("jit.trace.scan_banked")  # at trace time only
     b = jnp.take_along_axis(
         B.T.astype(vals.dtype), perm[lane_idx], axis=1
     )
@@ -320,6 +323,7 @@ def _solve_scan_mrhs(row_ids, col_idx, vals, diag, accum, b_pad, n):
     """Batched SpTRSM: ``b_pad`` f[n+1, m], carry ``x`` f[n+1, m]. One plan
     traversal solves all m right-hand sides (the gather/scatter indices are
     shared; only the value lanes widen)."""
+    obs.counter_add("jit.trace.scan_mrhs")  # at trace time only
     m = b_pad.shape[1]
     x0 = jnp.zeros((n + 1, m), dtype=b_pad.dtype)
     acc0 = jnp.zeros((row_ids.shape[1], m), dtype=b_pad.dtype)
@@ -415,6 +419,7 @@ def _elastic_single(row_ids, col_idx, vals, diag, accum, b_pad, n):
     every row still accumulates in exactly the plan order and the result
     is bitwise-identical to ``_scan_single``; only the scan trip count
     (and with it per-step dispatch overhead) shrinks."""
+    obs.counter_add("jit.trace.elastic")  # at trace time only
     S = row_ids.shape[1]
     x0 = jnp.zeros(n + 1, dtype=b_pad.dtype)
     acc0 = jnp.zeros(row_ids.shape[2], dtype=b_pad.dtype)
@@ -438,6 +443,7 @@ _solve_elastic = partial(jax.jit, static_argnames=("n",))(_elastic_single)
 @partial(jax.jit, static_argnames=("n",))
 def _solve_elastic_mrhs(row_ids, col_idx, vals, diag, accum, b_pad, n):
     """Multi-RHS elastic scan (macro-step twin of ``_solve_scan_mrhs``)."""
+    obs.counter_add("jit.trace.elastic_mrhs")  # at trace time only
     S = row_ids.shape[1]
     m = b_pad.shape[1]
     x0 = jnp.zeros((n + 1, m), dtype=b_pad.dtype)
@@ -484,6 +490,7 @@ def _solve_segment(rows, cols, v, d, a, b_pad, x, acc):
     carry. Serves both timed paths: a bulk superstep slice (rows
     int32[t, k]) and one elastic macro window (rows int32[slack, k]).
     Single- vs multi-RHS is resolved statically from the carry rank."""
+    obs.counter_add("jit.trace.segment")  # at trace time only
     body = _step_single if x.ndim == 1 else _step_mrhs
 
     def step(carry, inp):

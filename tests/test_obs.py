@@ -23,6 +23,7 @@ What this file pins down:
     unlocked deque iteration crashed with "deque mutated during
     iteration" under serving load.
 """
+import gc
 import json
 import threading
 
@@ -37,9 +38,14 @@ from repro.sparse.generators import erdos_renyi_lower
 
 @pytest.fixture(autouse=True)
 def _tracing_off():
-    """Every test starts and ends with tracing globally off."""
+    """Every test starts and ends with tracing globally off, and runs
+    with automatic garbage collection paused, so that no ``host.gc``
+    span joins the spans a test counts (``gc.collect()`` still runs and
+    is still recorded)."""
     obs.disable()
+    gc.disable()
     yield
+    gc.enable()
     obs.disable()
 
 
@@ -138,9 +144,11 @@ def test_threaded_spans_tag_their_thread():
             t.start()
         for t in ts:
             t.join()
-    assert len(buf) == n_threads * per
+    # a garbage collection during the run adds its own host.gc span
+    spans = [r for r in buf.spans() if r.name == "worker"]
+    assert len(spans) == n_threads * per
     assert buf.counters()["work.done"] == n_threads * per
-    assert len({r.tid for r in buf.spans()}) == n_threads
+    assert len({r.tid for r in spans}) == n_threads
 
 
 # --------------------------------------------------------------- counters
@@ -343,3 +351,156 @@ def test_latency_reservoir_threaded():
         t.join()
     assert not errors, f"reservoir raced: {errors[0]!r}"
     assert res.count > 0 and len(res.samples()) <= 256
+
+
+# ------------------------------------------- the profiler's clock (bridge)
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_bridge_opens_annotation_only_while_enabled(monkeypatch):
+    from repro.obs import trace
+
+    monkeypatch.setattr(trace, "_ANNOTATION", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    assert obs.span("off.probe", k=1) is obs.NULL_SPAN
+    with obs.span("off.probe"):
+        pass
+    assert _FakeAnnotation.log == []
+    buf = obs.TraceBuffer("bridge")
+    with obs.tracing(buf):
+        with obs.span("outer.probe", cat="x", n=3):
+            with obs.span("inner.probe"):
+                pass
+    # args stay off the annotation's name; nesting is kept
+    assert _FakeAnnotation.log == [
+        ("enter", "outer.probe"), ("enter", "inner.probe"),
+        ("exit", "inner.probe"), ("exit", "outer.probe"),
+    ]
+    assert [r.name for r in buf.spans()] == ["inner.probe", "outer.probe"]
+    _FakeAnnotation.log = []
+    assert obs.span("off.again") is obs.NULL_SPAN
+    assert _FakeAnnotation.log == []
+
+
+def test_spans_land_in_the_profiler_record(tmp_path):
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.tracing(obs.TraceBuffer("profiler")):
+            with obs.span("obs.bridge_probe", n=1):
+                jax.numpy.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    names = {
+        e.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert "obs.bridge_probe" in names
+
+
+# -------------------------------------------------------- GC pauses as spans
+def test_gc_collection_recorded_as_span():
+    from repro.obs import trace
+
+    buf = obs.TraceBuffer("gc")
+    with obs.tracing(buf):
+        assert trace._on_gc in gc.callbacks
+        gc.collect()
+    assert trace._on_gc not in gc.callbacks  # unregistered with tracing
+    pauses = [r for r in buf.spans() if r.name == "host.gc"]
+    assert pauses, "a forced collection left no host.gc span"
+    rec = pauses[-1]
+    assert rec.cat == "host" and rec.t1_ns >= rec.t0_ns
+    assert rec.args["generation"] == 2
+    assert isinstance(rec.args["collected"], int)
+    n = len(buf)
+    gc.collect()  # tracing off: nothing more recorded
+    assert len(buf) == n
+
+
+def test_add_record_off_and_on():
+    buf = obs.TraceBuffer("records")
+    obs.add_record("serve.request", 1.0, 2.0, id=1)  # off: dropped
+    with obs.tracing(buf):
+        obs.add_record("serve.request", 1.0, 2.5, id=7, queue_s=0.5)
+    (rec,) = buf.spans()
+    assert (rec.name, rec.cat, rec.tid) == ("serve.request", "serve", 0)
+    assert (rec.t0_ns, rec.t1_ns) == (1_000_000_000, 2_500_000_000)
+    assert rec.args == {"id": 7, "queue_s": 0.5}
+
+
+# ------------------------------------------------------- inspector phases
+@pytest.mark.parametrize("strategy", ["growlocal", "auto"])
+def test_inspector_schedule_span_pinned_and_auto(strategy):
+    L = _matrix(n=140, seed=11)
+    buf = obs.TraceBuffer(f"sched.{strategy}")
+    with obs.tracing(buf):
+        solver = TriangularSolver.plan(L, strategy=strategy)
+    sched = [r for r in buf.spans() if r.name == "inspector.schedule"]
+    assert len(sched) == 1
+    assert sched[0].args["strategy"] == strategy
+    if strategy == "auto":
+        assert sched[0].args["picked"] == solver.strategy
+    assert not [r for r in buf.spans()
+                if r.name.startswith("inspector.schedule.")]
+    (bind,) = [r for r in buf.spans() if r.name == "backend.bind"]
+    assert bind.args["backend"] == "scan"
+
+
+def test_bind_span_covers_the_elastic_certificate():
+    L = _matrix(n=160, seed=13)
+    buf = obs.TraceBuffer("bind")
+    with obs.tracing(buf):
+        solver = TriangularSolver.plan(L, strategy="growlocal", slack=4)
+    (bind,) = [r for r in buf.spans() if r.name == "backend.bind"]
+    assert bind.args["slack"] == 4
+    assert solver.exec_plan.elastic is not None
+    assert solver.exec_plan.elastic.slack == 4
+
+
+def test_solve_span_is_executor_dispatch():
+    L = _matrix(n=130, seed=17)
+    solver = TriangularSolver.plan(L, strategy="growlocal")
+    b = np.ones(L.n_rows, np.float32)
+    buf = obs.TraceBuffer("dispatch")
+    with obs.tracing(buf):
+        solver.solve(b)
+    assert [r.name for r in buf.spans()] == ["executor.dispatch"]
+
+
+# ------------------------------------------------ compile counter (traces)
+def test_jit_trace_counter_counts_only_retraces():
+    L = _matrix(n=151, seed=19)  # a size no other test solves
+    solver = TriangularSolver.plan(L, strategy="growlocal")
+    B = np.ones((L.n_rows, 7), np.float32)
+    buf = obs.TraceBuffer("jit")
+    with obs.tracing(buf):
+        solver.solve(B).block_until_ready()
+        first = buf.counters().get("jit.trace.scan_mrhs", 0)
+        solver.solve(B).block_until_ready()
+        again = buf.counters().get("jit.trace.scan_mrhs", 0) - first
+    assert (first, again) == (1, 0)

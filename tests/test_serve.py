@@ -524,3 +524,87 @@ def test_worker_failure_propagates_to_tickets(mats):
         assert x.shape == (n,)
         snap = svc.stats()
         assert snap["failed"] == 1 and snap["completed"] >= 1
+
+
+# ------------------------------------- tracing: batch phases and requests
+PHASES = ("serve.batch.stack", "serve.batch.dispatch", "serve.batch.wait",
+          "serve.batch.fulfil")
+BATCH_SPANS = {"microbatch": "serve.microbatch",
+               "continuous": "serve.slot_pass"}
+
+
+def _traced_burst(mats, mode, n_requests=12):
+    from repro import obs
+
+    buf = obs.TraceBuffer(f"serve.{mode}")
+    rng = np.random.default_rng(5)
+    with obs.tracing(buf):
+        with SolveService(
+            max_batch=4, max_wait_us=20_000, mode=mode, strategy=STRATEGY
+        ) as svc:
+            fp = svc.register(mats[0])
+            tickets = [
+                svc.submit(fp, rng.standard_normal(mats[0].n_rows))
+                for _ in range(n_requests)
+            ]
+            for t in tickets:
+                t.result(60)
+    return buf.spans(), tickets
+
+
+@pytest.mark.parametrize("mode", ["microbatch", "continuous"])
+def test_traced_batches_nest_their_phases(mats, mode):
+    spans, tickets = _traced_burst(mats, mode)
+    batches = [r for r in spans if r.name == BATCH_SPANS[mode]]
+    assert batches
+    ridden = {t.batch for t in tickets}
+    assert ridden <= {b.args["batch"] for b in batches}
+    for b in batches:
+        if b.args["batch"] not in ridden:
+            continue  # a continuous pass that admitted nothing
+        inside = [r for r in spans if r.args.get("batch") == b.args["batch"]
+                  and r.name in PHASES]
+        assert sorted(r.name for r in inside) == sorted(PHASES)
+        for r in inside:
+            assert r.tid == b.tid
+            assert b.t0_ns <= r.t0_ns <= r.t1_ns <= b.t1_ns
+        order = sorted(inside, key=lambda r: r.t0_ns)
+        assert [r.name for r in order] == list(PHASES)
+
+
+def test_traced_requests_one_record_per_ticket(mats):
+    spans, tickets = _traced_burst(mats, "microbatch")
+    records = [r for r in spans if r.name == "serve.request"]
+    assert len(records) == len(tickets)
+    by_id = {r.args["id"]: r for r in records}
+    assert len(by_id) == len(tickets)  # distinct ids
+    assert sorted(by_id) == sorted(t.request_id for t in tickets)
+    for t in tickets:
+        r = by_id[t.request_id]
+        assert r.args["batch"] == t.batch is not None
+        assert t.t_submit <= t.t_dispatch <= t.t_done
+        assert r.args["queue_s"] == t.t_dispatch - t.t_submit
+        assert r.t0_ns == int(t.t_submit * 1e9)
+        assert r.t1_ns == int(t.t_done * 1e9)
+
+
+def test_answers_bitwise_equal_traced_and_untraced(mats):
+    """Tracing changes nothing that runs: the same requests, served one
+    at a time (so each rides a batch of the same width and column), give
+    the same bits with tracing on and off."""
+    from repro import obs
+
+    rng = np.random.default_rng(9)
+    n = mats[1].n_rows
+    rhs = [rng.standard_normal(n) for _ in range(4)]
+
+    def serve():
+        with SolveService(max_batch=8, strategy=STRATEGY) as svc:
+            fp = svc.register(mats[1])
+            return [svc.submit(fp, b).result(60) for b in rhs]
+
+    off = serve()
+    with obs.tracing(obs.TraceBuffer("bitwise")):
+        on = serve()
+    for x_off, x_on in zip(off, on):
+        assert np.array_equal(x_off, x_on)
