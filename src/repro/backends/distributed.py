@@ -185,7 +185,7 @@ class RowShardedBoundSolve(BoundSolve):
         self._rsp = rsp  # core.rowshard.RowShardPlan (host tensors)
         self._mesh = mesh
         self._mode = mode  # "ring" | "psum"
-        self._plan_args = plan_args  # stacked [n_shards, T, k_local, ...]
+        self._plan_args = plan_args  # stacked [n_shards, T, ...], step layout
         self._halo_args = halo_args  # flat int32 exchange tables
         self._val_src = val_src  # stacked GLOBAL entry ids
         self._diag_src = diag_src
@@ -614,6 +614,7 @@ class DistributedBackend(Backend):
         import jax.numpy as jnp
 
         from repro.core.rowshard import partition_plan
+        from repro.solver.executor import w_major
         from repro.solver.rowsharded import (
             rowshard_halo_args,
             rowshard_plan_args,
@@ -635,9 +636,10 @@ class DistributedBackend(Backend):
         plan_args = rowshard_plan_args(rsp, dtype=jnp.dtype(np_dtype.name))
         mode = "ring"  # bitwise-safe default; psum is bench/opt-in
         halo_args = rowshard_halo_args(rsp, mode)
-        # GLOBAL entry ids per shard: one gather refreshes all shards
+        # GLOBAL entry ids per shard, in the executor's step layout: one
+        # gather refreshes all shards
         val_src = jnp.asarray(
-            np.stack([s.val_src for s in rsp.shards]), jnp.int32
+            w_major(np.stack([s.val_src for s in rsp.shards])), jnp.int32
         )
         diag_src = jnp.asarray(
             np.stack([s.diag_src for s in rsp.shards]), jnp.int32

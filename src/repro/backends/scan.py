@@ -23,7 +23,7 @@ class ScanBoundSolve(BoundSolve):
 
     def __init__(self, pa, val_src, diag_src, np_dtype, n_entries):
         self._pa = pa  # solver.executor.PlanArrays (device-resident)
-        self._val_src = val_src  # int32[T, k, W] device
+        self._val_src = val_src  # int32[T, W, k] device (step layout)
         self._diag_src = diag_src  # int32[T, k] device
         self._np_dtype = np_dtype
         self.n = pa.n
@@ -112,8 +112,7 @@ class ScanBoundSolve(BoundSolve):
         return new
 
     def describe(self) -> dict:
-        T, k = self._pa.row_ids.shape
-        W = self._pa.col_idx.shape[-1]
+        T, W, k = self._pa.vals.shape
         return {
             "backend": self.backend,
             "n": self.n,
@@ -142,7 +141,7 @@ class ElasticScanBoundSolve(BoundSolve):
     def __init__(self, ea, elastic, val_src, diag_src, np_dtype, n_entries):
         self._ea = ea  # solver.executor.ElasticArrays (device-resident)
         self._elastic = elastic  # core.elastic.ElasticPlan certificate
-        self._val_src = val_src  # int32[M, S, k, W] device (-1 padded)
+        self._val_src = val_src  # int32[M, S, W, k] device (-1 padded)
         self._diag_src = diag_src  # int32[M, S, k] device (-1 padded)
         self._np_dtype = np_dtype
         self.n = ea.n
@@ -196,8 +195,7 @@ class ElasticScanBoundSolve(BoundSolve):
         )
 
     def describe(self) -> dict:
-        M, S, k = self._ea.row_ids.shape
-        W = self._ea.col_idx.shape[-1]
+        M, S, W, k = self._ea.vals.shape
         cert = self._elastic.stats() if self._elastic is not None else {}
         rt = dict(self._runtime)
         if rt["timed_solves"]:
@@ -251,7 +249,7 @@ class ScanBackend(Backend):
             )
         import jax.numpy as jnp
 
-        from repro.solver.executor import plan_arrays
+        from repro.solver.executor import plan_arrays, w_major
 
         assert exec_plan.val_src is not None and exec_plan.diag_src is not None
         if slack > 0:
@@ -265,11 +263,12 @@ class ScanBackend(Backend):
             if ep is None or ep.slack != slack:
                 ep = elastic_transform(exec_plan, slack)
             ea = elastic_plan_arrays(exec_plan, slack=slack, dtype=dtype)
-            M, S = ea.row_ids.shape[:2]
+            M, S = ea.write_rows.shape[:2]
             pad = M * S - exec_plan.n_steps
-            # source maps ride the same window padding; -1 marks padding
-            # so device-side refreshes leave those slots untouched
-            val_src = _pad_to_window(exec_plan.val_src, pad, -1)
+            # source maps ride the same window padding and step layout;
+            # -1 marks padding so device-side refreshes leave those slots
+            # untouched
+            val_src = w_major(_pad_to_window(exec_plan.val_src, pad, -1))
             diag_src = _pad_to_window(exec_plan.diag_src, pad, -1)
             return ElasticScanBoundSolve(
                 ea,
@@ -284,7 +283,7 @@ class ScanBackend(Backend):
         pa = plan_arrays(exec_plan, dtype=dtype)
         return ScanBoundSolve(
             pa,
-            jnp.asarray(exec_plan.val_src, jnp.int32),
+            jnp.asarray(w_major(exec_plan.val_src), jnp.int32),
             jnp.asarray(exec_plan.diag_src, jnp.int32),
             np.dtype(dtype),
             expected_entry_count(exec_plan),

@@ -5,15 +5,27 @@ This module is the device half of the ``scan`` entry in
 (``get_backend("scan").bind(plan)``) unless you need the raw pieces.
 
 Each scan step processes one lock-step row per core (k rows in parallel on
-the VPU): gather x at the row's column indices, fused multiply-accumulate,
-divide by the diagonal, scatter into x. Same-core sequential chains flow
-through the scan carry; superstep barriers are free on one chip (DESIGN.md
-§3), so the scan ignores `step_bounds` — they matter for the distributed
-executor and the Pallas kernel grid.
+the VPU) with two device ops that touch x: ONE gather of every x value
+the step's k·W slots read, then a fixed-order multiply-accumulate and the
+divide by the diagonal, then ONE scatter of the k results into x.
+Same-core sequential chains flow through the scan carry; superstep
+barriers are free on one chip (DESIGN.md §3), so the scan ignores
+`step_bounds` — they matter for the distributed executor and the Pallas
+kernel grid.
+
+Step layout. The executor lays the plan out for itself at bind time
+(``ExecPlan`` keeps its [T, k, W] tensors): ``cols`` int32[T, W·k] holds
+the gather indices w-major and flat, so one ``x[cols]`` serves every
+slot of the step; ``vals`` f[T, W, k] the matching values; and
+``write_rows`` int32[T, k] the row each lane writes. The rhs is gathered
+once per solve, ``b[write_rows]``, before the loop, and rides the scan as
+one more per-step tensor.
 
 Padding protocol (see core.plan): row id n = scratch row, gather index n =
 scratch slot, so padded lanes are harmless. `accum` rows carry partial sums
-for rows wider than W.
+for rows wider than W; an accum lane writes the sink row n + 1, which no
+gather reads, so a step never reads x back to leave that row as it was.
+x is carried with n + 2 rows; b reads 0 at both extra rows.
 
 The elastic section at the bottom (``ElasticArrays`` /
 ``solve_with_elastic``) is the ``mode="elastic"`` variant: the same step
@@ -26,7 +38,6 @@ exact same op sequence.
 from __future__ import annotations
 
 import time
-from functools import partial
 from typing import List, NamedTuple, Tuple
 
 import jax
@@ -38,31 +49,77 @@ from repro.core.plan import ExecPlan
 
 
 class PlanArrays(NamedTuple):
-    """Device-resident plan tensors (see ExecPlan for shapes)."""
+    """Device-resident plan tensors in the step layout (module
+    docstring)."""
 
-    row_ids: jax.Array  # int32[T, k]
-    col_idx: jax.Array  # int32[T, k, W]
-    vals: jax.Array  # f[T, k, W]
+    write_rows: jax.Array  # int32[T, k]  accum lanes -> sink row n + 1
+    cols: jax.Array  # int32[T, W*k]  w-major gather indices
+    vals: jax.Array  # f[T, W, k]
     diag: jax.Array  # f[T, k]
     accum: jax.Array  # bool[T, k]
     n: int
     step_bounds: np.ndarray  # host-side; used by distributed executor
 
 
+def w_major(a: np.ndarray) -> np.ndarray:
+    """[..., k, W] -> [..., W, k]: a plan's per-slot tensor (``vals``,
+    ``val_src``) in the step layout."""
+    return np.swapaxes(a, -1, -2)
+
+
+def _pad_to_window(a: np.ndarray, pad: int, fill) -> np.ndarray:
+    if pad == 0:
+        return a
+    tail = np.full((pad, *a.shape[1:]), fill, dtype=a.dtype)
+    return np.concatenate([a, tail], axis=0)
+
+
+def laid_out(plan: ExecPlan, pad: int = 0):
+    """The plan's step tensors in the step layout, host-side, with
+    ``pad`` scratch steps appended (row n, gather n, val 0, diag 1, no
+    accum): ``(write_rows, cols, vals, diag, accum)``."""
+    n, k, W = plan.n, plan.k, plan.W
+    accum = _pad_to_window(plan.accum, pad, False)
+    cols = w_major(_pad_to_window(plan.col_idx, pad, n))
+    return (
+        np.where(accum, n + 1, _pad_to_window(plan.row_ids, pad, n)),
+        cols.reshape(cols.shape[0], W * k),
+        w_major(_pad_to_window(plan.vals, pad, 0)),
+        _pad_to_window(plan.diag, pad, 1),
+        accum,
+    )
+
+
+def to_device(host, dtype):
+    """``laid_out`` tensors (any leading shape) as device arrays."""
+    rows, cols, vals, diag, accum = host
+    return (
+        jnp.asarray(rows, jnp.int32),
+        jnp.asarray(cols, jnp.int32),
+        jnp.asarray(vals, dtype),
+        jnp.asarray(diag, dtype),
+        jnp.asarray(accum),
+    )
+
+
 def plan_arrays(plan: ExecPlan, dtype=jnp.float32) -> PlanArrays:
     return PlanArrays(
-        row_ids=jnp.asarray(plan.row_ids, dtype=jnp.int32),
-        col_idx=jnp.asarray(plan.col_idx, dtype=jnp.int32),
-        vals=jnp.asarray(plan.vals, dtype=dtype),
-        diag=jnp.asarray(plan.diag, dtype=dtype),
-        accum=jnp.asarray(plan.accum),
+        *to_device(laid_out(plan), dtype),
         n=plan.n,
         step_bounds=np.asarray(plan.step_bounds),
     )
 
 
-def _step_single(x, acc, rows, cols, v, d, a, b_pad):
-    """One plan step: gather, fused multiply-accumulate, divide, scatter.
+def pad_rhs(b, rows: int = 2):
+    """``b`` f[n(, m)] with ``rows`` zero rows appended: the scratch row
+    n and the sink n + 1 read 0."""
+    return jnp.concatenate([b, jnp.zeros((rows, *b.shape[1:]), b.dtype)])
+
+
+def _step_single(x, acc, rows, cols, v, d, a, bt):
+    """One plan step: one gather, fused multiply-accumulate, divide, one
+    scatter. ``rows``/``bt``/``d``/``a`` are [k], ``cols`` [W·k], ``v``
+    [W, k] (step layout, module docstring).
 
     Shared verbatim by the bulk-synchronous scan, the elastic macro-step
     executor AND the row-sharded distributed executor
@@ -82,71 +139,116 @@ def _step_single(x, acc, rows, cols, v, d, a, b_pad):
     # named_scope tags the emitted HLO (zero runtime cost), so a
     # jax.profiler device trace carries plan-step names
     with jax.named_scope("sptrsv_step"):
-        for w in range(v.shape[1]):
-            acc = acc + v[:, w] * x[cols[:, w]]
-        xv = (b_pad[rows] - acc) / d
-        # finishing lanes write x and reset their accumulator
-        write = jnp.where(a, x[rows], xv)
-        # NOTE: padded lanes share the scratch row id n -> indices are not
-        # unique; plain scatter keeps them well-defined (they all write
-        # junk to the scratch slot).
-        x = x.at[rows].set(write)
+        k = acc.shape[0]
+        xg = x[cols]
+        for w in range(v.shape[0]):
+            acc = acc + v[w] * xg[w * k:(w + 1) * k]
+        # padded lanes share the scratch row n and accum lanes the sink
+        # n + 1 -> indices are not unique; plain scatter keeps them
+        # well-defined (they all write junk to those two rows)
+        x = x.at[rows].set((bt - acc) / d)
         acc = jnp.where(a, acc, 0.0)
     return x, acc
 
 
-def _scan_single(row_ids, col_idx, vals, diag, accum, b_pad, n):
+def _step_mrhs(x, acc, rows, cols, v, d, a, bt):
+    """Multi-RHS twin of ``_step_single`` (value lanes widen to m);
+    shared by the bulk scan, the elastic macro-step body and the
+    row-sharded executor. Same fixed-order elementwise W-reduction as
+    ``_step_single`` — a column's bits are independent of both the lane
+    count k and the batch width m."""
+    with jax.named_scope("sptrsv_step_mrhs"):
+        k = acc.shape[0]
+        xg = x[cols]
+        for w in range(v.shape[0]):
+            acc = acc + v[w, :, None] * xg[w * k:(w + 1) * k]
+        x = x.at[rows].set((bt - acc) / d[:, None])
+        acc = jnp.where(a[:, None], acc, 0.0)
+    return x, acc
+
+
+def walk(step, carry, steps, b_pad, window: int = 0):
+    """Run ``step`` over ``steps`` = (write_rows, cols, vals, diag,
+    accum), leading axis first, from ``carry`` = (x, acc). b is gathered
+    for every step at once, before the loop. With ``window`` the leading
+    axis is the elastic macro-step: each scan step replays its
+    ``window`` plan steps in order, unrolled."""
+
+    def body(carry, inp):
+        if not window:
+            return step(*carry, *inp), None
+        for j in range(window):
+            carry = step(*carry, *(t[j] for t in inp))
+        return carry, None
+
+    carry, _ = jax.lax.scan(body, carry, (*steps, b_pad[steps[0]]))
+    return carry
+
+
+def _solve_steps(steps, b, window: int = 0):
+    """x f[n(, m)] with L x = b, b f[n(, m)], over laid-out plan steps."""
+    step = _step_single if b.ndim == 1 else _step_mrhs
+    b_pad = pad_rhs(b)
+    acc0 = jnp.zeros((steps[0].shape[-1], *b.shape[1:]), b.dtype)
+    x, _ = walk(step, (jnp.zeros_like(b_pad), acc0), steps, b_pad, window)
+    return x[: b.shape[0]]
+
+
+@jax.jit
+def _solve_scan(write_rows, cols, vals, diag, accum, b):
     obs.counter_add("jit.trace.scan")  # at trace time only
-    x0 = jnp.zeros(n + 1, dtype=b_pad.dtype)
-    acc0 = jnp.zeros(row_ids.shape[1], dtype=b_pad.dtype)
-
-    def step(carry, inp):
-        return _step_single(*carry, *inp, b_pad), None
-
-    (x, _), _ = jax.lax.scan(
-        step, (x0, acc0), (row_ids, col_idx, vals, diag, accum)
-    )
-    return x[:n]
+    return _solve_steps((write_rows, cols, vals, diag, accum), b)
 
 
-_solve_scan = partial(jax.jit, static_argnames=("n",))(_scan_single)
+@jax.jit
+def _solve_scan_mrhs(write_rows, cols, vals, diag, accum, b):
+    """Batched SpTRSM: ``b`` f[n, m], carry ``x`` f[n+2, m]. One plan
+    traversal solves all m right-hand sides (the gather/scatter indices are
+    shared; only the value lanes widen)."""
+    obs.counter_add("jit.trace.scan_mrhs")  # at trace time only
+    return _solve_steps((write_rows, cols, vals, diag, accum), b)
 
 
-def _scan_lanes(row_ids, col_idx, vals, diag, accum, lane_idx, b_pad, n):
+def _scan_lanes(write_rows, cols, vals, diag, accum, lane_idx, b):
     """Lane j runs the single-RHS scan on plan ``lane_idx[j]`` of the
-    stacked plan tensors (leading plan axis P) with rhs ``b_pad[j]``.
+    stacked plan tensors (leading plan axis P) with rhs ``b[j]`` f[n].
 
     Each scan step slices step t out of every plan and gathers the
     lanes' rows of that slice, so lanes that share a plan never copy its
     tensors: a [lanes, T, k, W] copy of a 64^3 IC(0) plan at 32 lanes
     asks a TPU v5e compile for 26 GB of HBM once the minor dimensions
-    are padded to the (8, 128) tile. Lanes are
+    are padded to the (8, 128) tile. For the same reason b is gathered
+    inside the step here, not for all steps up front: that would be a
+    [lanes, T, k] tensor, k padded to the tile's 128. Lanes are
     data-independent: the vmapped step runs the same op sequence per
     lane, so a lane's bits never depend on what its neighbors hold
     (property-tested in tests/test_serve_scaleout.py)."""
     obs.counter_add("jit.trace.scan_lanes")  # at trace time only
-    k = row_ids.shape[2]
-    w = lane_idx.shape[0]
+    b_pad = jax.vmap(pad_rhs)(b)
     step = jax.vmap(_step_single)
 
     def body(carry, t):
         def lanes(a):
             return jax.lax.dynamic_index_in_dim(a, t, 1, False)[lane_idx]
 
+        rows = lanes(write_rows)
+        bt = jnp.take_along_axis(b_pad, rows, axis=1)
         return step(
-            *carry, lanes(row_ids), lanes(col_idx), lanes(vals),
-            lanes(diag), lanes(accum), b_pad,
+            *carry, rows, lanes(cols), lanes(vals), lanes(diag),
+            lanes(accum), bt,
         ), None
 
-    x0 = jnp.zeros((w, n + 1), b_pad.dtype)
-    acc0 = jnp.zeros((w, k), b_pad.dtype)
+    acc0 = jnp.zeros((b.shape[0], write_rows.shape[2]), b.dtype)
     (x, _), _ = jax.lax.scan(
-        body, (x0, acc0), jnp.arange(row_ids.shape[1])
+        body, (jnp.zeros_like(b_pad), acc0), jnp.arange(write_rows.shape[1])
     )
-    return x[:, :n]
+    return x[:, : b.shape[1]]
 
 
-_solve_scan_lanes = partial(jax.jit, static_argnames=("n",))(_scan_lanes)
+_solve_scan_lanes = jax.jit(_scan_lanes)
+
+# the plan tensors a grouped or banked solve stacks, in PlanArrays order
+_STEP_FIELDS = ("write_rows", "cols", "vals", "diag", "accum")
 
 
 def solve_with_plan_group(pas, b_cols: jax.Array) -> jax.Array:
@@ -160,21 +262,16 @@ def solve_with_plan_group(pas, b_cols: jax.Array) -> jax.Array:
     through a ``BankTensors`` bank + ``_solve_scan_banked`` instead
     (bitwise-identical output, asserted in
     tests/test_serve_scaleout.py)."""
-    dtype = pas[0].vals.dtype
-    b = jnp.asarray(b_cols, dtype)
-    b_pad = jnp.concatenate([b, jnp.zeros((b.shape[0], 1), dtype)], axis=1)
+    b = jnp.asarray(b_cols, pas[0].vals.dtype)
     first = {}
     lane_idx = np.array(
         [first.setdefault(id(pa), len(first)) for pa in pas], np.int32
     )
     uniq = list({id(pa): pa for pa in pas}.values())
     stacked = [
-        jnp.stack([getattr(pa, f) for pa in uniq])
-        for f in ("row_ids", "col_idx", "vals", "diag", "accum")
+        jnp.stack([getattr(pa, f) for pa in uniq]) for f in _STEP_FIELDS
     ]
-    return _solve_scan_lanes(
-        *stacked, jnp.asarray(lane_idx), b_pad, pas[0].n
-    )
+    return _solve_scan_lanes(*stacked, jnp.asarray(lane_idx), b)
 
 
 class BankTensors(NamedTuple):
@@ -185,9 +282,9 @@ class BankTensors(NamedTuple):
     with no per-dispatch stacking; the bank is only restacked when the
     class membership changes (new pattern or plan version)."""
 
-    row_ids: jax.Array  # int32[P, T, k]
-    col_idx: jax.Array  # int32[P, T, k, W]
-    vals: jax.Array  # f[P, T, k, W]
+    write_rows: jax.Array  # int32[P, T, k]
+    cols: jax.Array  # int32[P, T, W*k]
+    vals: jax.Array  # f[P, T, W, k]
     diag: jax.Array  # f[P, T, k]
     accum: jax.Array  # bool[P, T, k]
     perm: jax.Array  # int32[P, n]  caller order -> plan row order
@@ -203,18 +300,15 @@ def stack_plan_bank(pas, perms, invs) -> BankTensors:
     pad = (1 << max(P - 1, 0).bit_length()) - P if P > 1 else 0
     idx = list(range(P)) + [0] * pad
     return BankTensors(
-        *(
-            jnp.stack([getattr(pas[i], f) for i in idx])
-            for f in ("row_ids", "col_idx", "vals", "diag", "accum")
-        ),
+        *(jnp.stack([getattr(pas[i], f) for i in idx]) for f in _STEP_FIELDS),
         perm=jnp.stack([perms[i] for i in idx]),
         inv=jnp.stack([invs[i] for i in idx]),
     )
 
 
-@partial(jax.jit, static_argnames=("n",))
+@jax.jit
 def _solve_scan_banked(
-    row_ids, col_idx, vals, diag, accum, perm, inv, lane_idx, B, n
+    write_rows, cols, vals, diag, accum, perm, inv, lane_idx, B
 ):
     """The banked grouped solve: request j reads bank lane
     ``lane_idx[j]`` — plan tensors AND its row permutation — solves, and
@@ -226,19 +320,15 @@ def _solve_scan_banked(
     b = jnp.take_along_axis(
         B.T.astype(vals.dtype), perm[lane_idx], axis=1
     )
-    b_pad = jnp.concatenate(
-        [b, jnp.zeros((b.shape[0], 1), b.dtype)], axis=1
-    )
-    x = _scan_lanes(row_ids, col_idx, vals, diag, accum, lane_idx, b_pad, n)
+    x = _scan_lanes(write_rows, cols, vals, diag, accum, lane_idx, b)
     return jnp.take_along_axis(x, inv[lane_idx], axis=1).T
 
 
 def solve_with_bank(bank: BankTensors, lane_idx, B) -> jax.Array:
     """Solve column j of ``B`` f[n, m] (caller order) against bank lane
     ``lane_idx[j]``; returns x f[n, m] (caller order)."""
-    n = int(bank.perm.shape[1])
     return _solve_scan_banked(
-        *bank, jnp.asarray(lane_idx, jnp.int32), jnp.asarray(B), n
+        *bank, jnp.asarray(lane_idx, jnp.int32), jnp.asarray(B)
     )
 
 
@@ -302,49 +392,12 @@ def solve_resident(bank: BankTensors, lane_idx, B_res) -> jax.Array:
     return solve_with_bank(bank, lane_idx, B_res)
 
 
-def _step_mrhs(x, acc, rows, cols, v, d, a, b_pad):
-    """Multi-RHS twin of ``_step_single`` (value lanes widen to m);
-    shared by the bulk scan, the elastic macro-step body and the
-    row-sharded executor. Same fixed-order elementwise W-reduction as
-    ``_step_single`` — a column's bits are independent of both the lane
-    count k and the batch width m."""
-    with jax.named_scope("sptrsv_step_mrhs"):
-        for w in range(v.shape[1]):
-            acc = acc + v[:, w, None] * x[cols[:, w]]
-        xv = (b_pad[rows] - acc) / d[:, None]
-        write = jnp.where(a[:, None], x[rows], xv)
-        x = x.at[rows].set(write)
-        acc = jnp.where(a[:, None], acc, 0.0)
-    return x, acc
-
-
-@partial(jax.jit, static_argnames=("n",))
-def _solve_scan_mrhs(row_ids, col_idx, vals, diag, accum, b_pad, n):
-    """Batched SpTRSM: ``b_pad`` f[n+1, m], carry ``x`` f[n+1, m]. One plan
-    traversal solves all m right-hand sides (the gather/scatter indices are
-    shared; only the value lanes widen)."""
-    obs.counter_add("jit.trace.scan_mrhs")  # at trace time only
-    m = b_pad.shape[1]
-    x0 = jnp.zeros((n + 1, m), dtype=b_pad.dtype)
-    acc0 = jnp.zeros((row_ids.shape[1], m), dtype=b_pad.dtype)
-
-    def step(carry, inp):
-        return _step_mrhs(*carry, *inp, b_pad), None
-
-    (x, _), _ = jax.lax.scan(
-        step, (x0, acc0), (row_ids, col_idx, vals, diag, accum)
-    )
-    return x[:n]
-
-
 def solve_with_plan(pa: PlanArrays, b: jax.Array) -> jax.Array:
     """Solve L x = b using the compiled plan. ``b``: f[n] or f[n, m]
     (multi-RHS — solved in one batched traversal)."""
     b = b.astype(pa.vals.dtype)
-    pad = jnp.zeros((1, *b.shape[1:]), pa.vals.dtype)
-    b_pad = jnp.concatenate([b, pad])
     solver = _solve_scan if b.ndim == 1 else _solve_scan_mrhs
-    return solver(pa.row_ids, pa.col_idx, pa.vals, pa.diag, pa.accum, b_pad, pa.n)
+    return solver(*pa[:5], b)
 
 
 # --------------------------------------------------------------- elastic
@@ -353,23 +406,16 @@ class ElasticArrays(NamedTuple):
     steps, padded up to ``M * slack`` with scratch steps, reshaped to a
     leading [M, slack] grid. ``lax.scan`` runs over the M macro-steps;
     the slack axis is unrolled inside the step body (see
-    ``_elastic_single``)."""
+    ``_solve_elastic``)."""
 
-    row_ids: jax.Array  # int32[M, S, k]
-    col_idx: jax.Array  # int32[M, S, k, W]
-    vals: jax.Array  # f[M, S, k, W]
+    write_rows: jax.Array  # int32[M, S, k]
+    cols: jax.Array  # int32[M, S, W*k]
+    vals: jax.Array  # f[M, S, W, k]
     diag: jax.Array  # f[M, S, k]
     accum: jax.Array  # bool[M, S, k]
     n: int
     slack: int
     n_steps: int  # original (pre-padding) plan step count T
-
-
-def _pad_to_window(a: np.ndarray, pad: int, fill) -> np.ndarray:
-    if pad == 0:
-        return a
-    tail = np.full((pad, *a.shape[1:]), fill, dtype=a.dtype)
-    return np.concatenate([a, tail], axis=0)
 
 
 def elastic_plan_arrays(
@@ -383,93 +429,43 @@ def elastic_plan_arrays(
     (every virtual-row chain ends with its finishing row)."""
     T = plan.n_steps
     M = max(1, -(-T // slack))
-    pad = M * slack - T
-    n, k, W = plan.n, plan.k, plan.W
+    host = laid_out(plan, M * slack - T)
     return ElasticArrays(
-        row_ids=jnp.asarray(
-            _pad_to_window(plan.row_ids, pad, n).reshape(M, slack, k),
-            dtype=jnp.int32,
-        ),
-        col_idx=jnp.asarray(
-            _pad_to_window(plan.col_idx, pad, n).reshape(M, slack, k, W),
-            dtype=jnp.int32,
-        ),
-        vals=jnp.asarray(
-            _pad_to_window(plan.vals, pad, 0).reshape(M, slack, k, W),
-            dtype=dtype,
-        ),
-        diag=jnp.asarray(
-            _pad_to_window(plan.diag, pad, 1).reshape(M, slack, k),
-            dtype=dtype,
-        ),
-        accum=jnp.asarray(
-            _pad_to_window(plan.accum, pad, False).reshape(M, slack, k)
-        ),
-        n=n,
+        *to_device([a.reshape(M, slack, *a.shape[1:]) for a in host], dtype),
+        n=plan.n,
         slack=int(slack),
         n_steps=T,
     )
 
 
-def _elastic_single(row_ids, col_idx, vals, diag, accum, b_pad, n):
+@jax.jit
+def _solve_elastic(write_rows, cols, vals, diag, accum, b):
     """Elastic scan: ``ceil(T / slack)`` fused macro-steps. Each scan
     step replays its window's ``slack`` plan steps in order through the
     statically-unrolled ``_step_single`` body — intra-window
     dependencies resolve by local substitution on the live x carry, so
     every row still accumulates in exactly the plan order and the result
-    is bitwise-identical to ``_scan_single``; only the scan trip count
+    is bitwise-identical to ``_solve_scan``; only the scan trip count
     (and with it per-step dispatch overhead) shrinks."""
     obs.counter_add("jit.trace.elastic")  # at trace time only
-    S = row_ids.shape[1]
-    x0 = jnp.zeros(n + 1, dtype=b_pad.dtype)
-    acc0 = jnp.zeros(row_ids.shape[2], dtype=b_pad.dtype)
-
-    def macro(carry, inp):
-        x, acc = carry
-        rows, cols, v, d, a = inp
-        for j in range(S):
-            x, acc = _step_single(x, acc, rows[j], cols[j], v[j], d[j], a[j], b_pad)
-        return (x, acc), None
-
-    (x, _), _ = jax.lax.scan(
-        macro, (x0, acc0), (row_ids, col_idx, vals, diag, accum)
-    )
-    return x[:n]
+    steps = (write_rows, cols, vals, diag, accum)
+    return _solve_steps(steps, b, window=write_rows.shape[1])
 
 
-_solve_elastic = partial(jax.jit, static_argnames=("n",))(_elastic_single)
-
-
-@partial(jax.jit, static_argnames=("n",))
-def _solve_elastic_mrhs(row_ids, col_idx, vals, diag, accum, b_pad, n):
+@jax.jit
+def _solve_elastic_mrhs(write_rows, cols, vals, diag, accum, b):
     """Multi-RHS elastic scan (macro-step twin of ``_solve_scan_mrhs``)."""
     obs.counter_add("jit.trace.elastic_mrhs")  # at trace time only
-    S = row_ids.shape[1]
-    m = b_pad.shape[1]
-    x0 = jnp.zeros((n + 1, m), dtype=b_pad.dtype)
-    acc0 = jnp.zeros((row_ids.shape[2], m), dtype=b_pad.dtype)
-
-    def macro(carry, inp):
-        x, acc = carry
-        rows, cols, v, d, a = inp
-        for j in range(S):
-            x, acc = _step_mrhs(x, acc, rows[j], cols[j], v[j], d[j], a[j], b_pad)
-        return (x, acc), None
-
-    (x, _), _ = jax.lax.scan(
-        macro, (x0, acc0), (row_ids, col_idx, vals, diag, accum)
-    )
-    return x[:n]
+    steps = (write_rows, cols, vals, diag, accum)
+    return _solve_steps(steps, b, window=write_rows.shape[1])
 
 
 def solve_with_elastic(ea: ElasticArrays, b: jax.Array) -> jax.Array:
     """Solve L x = b through the elastic macro-step scan. ``b``: f[n] or
     f[n, m]; bitwise-identical to ``solve_with_plan`` on the same plan."""
     b = b.astype(ea.vals.dtype)
-    pad = jnp.zeros((1, *b.shape[1:]), ea.vals.dtype)
-    b_pad = jnp.concatenate([b, pad])
     solver = _solve_elastic if b.ndim == 1 else _solve_elastic_mrhs
-    return solver(ea.row_ids, ea.col_idx, ea.vals, ea.diag, ea.accum, b_pad, ea.n)
+    return solver(*ea[:5], b)
 
 
 # ---------------------------------------------------------- timed solves
@@ -485,34 +481,21 @@ def solve_with_elastic(ea: ElasticArrays, b: jax.Array) -> jax.Array:
 # total for elastic (every window is [slack, ...]-shaped).
 
 @jax.jit
-def _solve_segment(rows, cols, v, d, a, b_pad, x, acc):
+def _solve_segment(write_rows, cols, v, d, a, b_pad, x, acc):
     """Run one contiguous run of plan steps on an existing (x, acc)
     carry. Serves both timed paths: a bulk superstep slice (rows
     int32[t, k]) and one elastic macro window (rows int32[slack, k]).
     Single- vs multi-RHS is resolved statically from the carry rank."""
     obs.counter_add("jit.trace.segment")  # at trace time only
     body = _step_single if x.ndim == 1 else _step_mrhs
-
-    def step(carry, inp):
-        return body(*carry, *inp, b_pad), None
-
-    (x, acc), _ = jax.lax.scan(step, (x, acc), (rows, cols, v, d, a))
-    return x, acc
+    return walk(body, (x, acc), (write_rows, cols, v, d, a), b_pad)
 
 
-def _timed_carry(b, vals_dtype, n, k):
+def _timed_carry(b, vals_dtype, k):
     """Shared setup for the timed paths: padded rhs + zero carry."""
     b = jnp.asarray(b).astype(vals_dtype)
-    pad = jnp.zeros((1, *b.shape[1:]), vals_dtype)
-    b_pad = jnp.concatenate([b, pad])
-    if b.ndim == 1:
-        x = jnp.zeros(n + 1, b_pad.dtype)
-        acc = jnp.zeros(k, b_pad.dtype)
-    else:
-        m = b.shape[1]
-        x = jnp.zeros((n + 1, m), b_pad.dtype)
-        acc = jnp.zeros((k, m), b_pad.dtype)
-    return b_pad, x, acc
+    b_pad = pad_rhs(b)
+    return b_pad, jnp.zeros_like(b_pad), jnp.zeros((k, *b.shape[1:]), b.dtype)
 
 
 def solve_with_plan_timed(
@@ -523,8 +506,8 @@ def solve_with_plan_timed(
     ``(x, steps)`` where each entry is
     ``{"superstep", "n_steps", "us"}``; an ``executor.superstep`` span
     lands in the active trace buffer per segment when tracing is on."""
-    k = int(pa.row_ids.shape[1])
-    b_pad, x, acc = _timed_carry(b, pa.vals.dtype, pa.n, k)
+    k = int(pa.write_rows.shape[1])
+    b_pad, x, acc = _timed_carry(b, pa.vals.dtype, k)
     bounds = pa.step_bounds
     steps: List[dict] = []
     for s in range(len(bounds) - 1):
@@ -536,14 +519,7 @@ def solve_with_plan_timed(
         ):
             t0 = time.perf_counter_ns()
             x, acc = _solve_segment(
-                pa.row_ids[lo:hi],
-                pa.col_idx[lo:hi],
-                pa.vals[lo:hi],
-                pa.diag[lo:hi],
-                pa.accum[lo:hi],
-                b_pad,
-                x,
-                acc,
+                *(t[lo:hi] for t in pa[:5]), b_pad, x, acc
             )
             x.block_until_ready()
             dur = time.perf_counter_ns() - t0
@@ -562,25 +538,16 @@ def solve_with_elastic_timed(
     ``{"macro_step", "n_steps", "us"}`` entry (and one
     ``executor.macro_step`` span when tracing) per executed macro-step —
     the runtime side of the elastic barrier-fusion certificate."""
-    k = int(ea.row_ids.shape[2])
-    b_pad, x, acc = _timed_carry(b, ea.vals.dtype, ea.n, k)
-    M = int(ea.row_ids.shape[0])
+    k = int(ea.write_rows.shape[2])
+    b_pad, x, acc = _timed_carry(b, ea.vals.dtype, k)
+    M = int(ea.write_rows.shape[0])
     steps: List[dict] = []
     for m in range(M):
         with obs.span(
             "executor.macro_step", cat="executor", macro=m, slack=ea.slack
         ):
             t0 = time.perf_counter_ns()
-            x, acc = _solve_segment(
-                ea.row_ids[m],
-                ea.col_idx[m],
-                ea.vals[m],
-                ea.diag[m],
-                ea.accum[m],
-                b_pad,
-                x,
-                acc,
-            )
+            x, acc = _solve_segment(*(t[m] for t in ea[:5]), b_pad, x, acc)
             x.block_until_ready()
             dur = time.perf_counter_ns() - t0
         steps.append(

@@ -8,9 +8,11 @@ Each ``model``-axis device owns one shard: a contiguous block of
 ``k_local`` schedule cores and their rows. Its x-buffer is *resident* —
 ``[owned | halo | scratch]`` local slots — and a solve is the ordinary
 scan over the shard's local ``ExecPlan`` (the exact ``_step_single`` /
-``_step_mrhs`` bodies from ``solver.executor``, so per-row arithmetic is
-bitwise-identical to the single-chip scan), punctuated by one halo
-exchange per barrier round. Unlike the model-axis executor
+``_step_mrhs`` bodies and step layout from ``solver.executor``, so
+per-row arithmetic is bitwise-identical to the single-chip scan),
+punctuated by one halo exchange per barrier round. The scan carries one
+slot past the local slots: the sink that the step's ``accum`` lanes
+write. Unlike the model-axis executor
 (``solver.distributed``), which ``all_gather``s every core's xv at every
 superstep, the exchange moves ONLY the boundary values some other shard
 actually reads — static index tensors computed at partition time.
@@ -41,7 +43,14 @@ from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.rowshard import RowShardPlan
-from repro.solver.executor import _step_mrhs, _step_single
+from repro.solver.executor import (
+    _step_mrhs,
+    _step_single,
+    laid_out,
+    pad_rhs,
+    to_device,
+    walk,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,15 +118,11 @@ def rowshard_spec(rsp: RowShardPlan, *, mode="ring", batch=0) -> RowShardSpec:
 
 
 def rowshard_plan_args(rsp: RowShardPlan, dtype=jnp.float32):
-    """Stack the per-shard plans into device operands
-    [n_shards, T, k_local, ...] (sharded over ``model`` by shard_map)."""
-    return (
-        jnp.asarray(np.stack([s.row_ids for s in rsp.shards]), jnp.int32),
-        jnp.asarray(np.stack([s.col_idx for s in rsp.shards]), jnp.int32),
-        jnp.asarray(np.stack([s.vals for s in rsp.shards]), dtype),
-        jnp.asarray(np.stack([s.diag for s in rsp.shards]), dtype),
-        jnp.asarray(np.stack([s.accum for s in rsp.shards])),
-    )
+    """Stack the per-shard plans, in the scan executor's step layout,
+    into device operands [n_shards, T, ...] (sharded over ``model`` by
+    shard_map)."""
+    per_shard = zip(*(laid_out(s) for s in rsp.shards))
+    return to_device([np.stack(t) for t in per_shard], dtype)
 
 
 def rowshard_halo_args(rsp: RowShardPlan, mode="ring"):
@@ -141,9 +146,9 @@ def rowshard_halo_args(rsp: RowShardPlan, mode="ring"):
 
 
 PLAN_SPECS = (
-    P("model", None, None),  # row_ids [n_shards, T, k_local]
-    P("model", None, None, None),  # col_idx
-    P("model", None, None, None),  # vals
+    P("model", None, None),  # write_rows [n_shards, T, k_local]
+    P("model", None, None),  # cols [n_shards, T, W * k_local]
+    P("model", None, None, None),  # vals [n_shards, T, W, k_local]
     P("model", None, None),  # diag
     P("model", None, None),  # accum
 )
@@ -202,22 +207,20 @@ def _group_tables(spec: RowShardSpec, flat):
     return rounds
 
 
-def _run_round(spec, step, x, acc, rows, cols, vals, diag, accum, b_pad, r):
+def _run_round(spec, step, x, acc, plan, b_pad, r):
     """Scan the plan steps of exchange round ``r`` on the carry."""
     sb, eb = spec.step_bounds, spec.exchange_bounds
     lo, hi = sb[eb[r]], sb[eb[r + 1]]
     if hi == lo:
         return x, acc
+    return walk(step, (x, acc), tuple(t[lo:hi] for t in plan), b_pad)
 
-    def scan_step(carry, inp):
-        return step(*carry, *inp, b_pad), None
 
-    (x, acc), _ = jax.lax.scan(
-        scan_step,
-        (x, acc),
-        (rows[lo:hi], cols[lo:hi], vals[lo:hi], diag[lo:hi], accum[lo:hi]),
-    )
-    return x, acc
+def _local_operands(args):
+    """The shard's plan tensors and its rhs with the sink row appended,
+    from shard_map's operands (whose size-1 shard axis is stripped)."""
+    plan = tuple(a[0] for a in args[:5])
+    return plan, pad_rhs(args[-1][0], 1)
 
 
 def build_rowsharded_solver(spec: RowShardSpec, mesh: Mesh):
@@ -239,23 +242,14 @@ def build_rowsharded_solver(spec: RowShardSpec, mesh: Mesh):
     b_spec = P("model", None, "data") if mrhs else P("model", None)
     out_spec = P("model", None, "data") if mrhs else P("model", None)
 
-    def body(rows, cols, vals, diag, accum, *rest):
-        halo = _group_tables(spec, rest[:-1])
-        # strip the size-1 shard axis shard_map leaves on every operand
-        rows, cols, vals = rows[0], cols[0], vals[0]
-        diag, accum, b_pad = diag[0], accum[0], rest[-1][0]
+    def body(*args):
+        halo = _group_tables(spec, args[5:-1])
+        plan, b_pad = _local_operands(args)
         step = _step_mrhs if mrhs else _step_single
-        if mrhs:
-            m = b_pad.shape[1]
-            x = jnp.zeros((spec.slots, m), b_pad.dtype)
-            acc = jnp.zeros((spec.k_local, m), b_pad.dtype)
-        else:
-            x = jnp.zeros(spec.slots, b_pad.dtype)
-            acc = jnp.zeros(spec.k_local, b_pad.dtype)
+        x = jnp.zeros_like(b_pad)
+        acc = jnp.zeros((spec.k_local, *b_pad.shape[1:]), b_pad.dtype)
         for r in range(spec.n_rounds):
-            x, acc = _run_round(
-                spec, step, x, acc, rows, cols, vals, diag, accum, b_pad, r
-            )
+            x, acc = _run_round(spec, step, x, acc, plan, b_pad, r)
             if r < spec.n_rounds - 1:
                 if spec.mode == "ring":
                     x = _exchange_ring(
@@ -289,19 +283,15 @@ def build_rowsharded_round(spec: RowShardSpec, mesh: Mesh, r: int):
     halo_specs = (P("model", None),) * n_halo_args
     xb_spec = P("model", None, "data") if mrhs else P("model", None)
 
-    def body(rows, cols, vals, diag, accum, *rest):
-        halo = rest[:n_halo_args]
-        b_pad, x = rest[-2][0], rest[-1][0]
-        rows, cols, vals = rows[0], cols[0], vals[0]
-        diag, accum = diag[0], accum[0]
+    def body(*args):
+        halo = args[5:5 + n_halo_args]
+        plan, b_pad = _local_operands(args[:-1])
+        # the carried x gets the sink row for this round's scan only
+        x = pad_rhs(args[-1][0], 1)
         step = _step_mrhs if mrhs else _step_single
-        if mrhs:
-            acc = jnp.zeros((spec.k_local, b_pad.shape[1]), b_pad.dtype)
-        else:
-            acc = jnp.zeros(spec.k_local, b_pad.dtype)
-        x, acc = _run_round(
-            spec, step, x, acc, rows, cols, vals, diag, accum, b_pad, r
-        )
+        acc = jnp.zeros((spec.k_local, *b_pad.shape[1:]), b_pad.dtype)
+        x, acc = _run_round(spec, step, x, acc, plan, b_pad, r)
+        x = x[:-1]
         if do_exchange:
             if spec.mode == "ring":
                 tabs = tuple(

@@ -22,6 +22,7 @@ Three layers of guarantees:
 import numpy as np
 import pytest
 from _hyp import given, settings, strategies as st
+from _oracle import solver_oracle
 
 from repro.autotune import clear_selection_memo, corpus_entry, corpus_names
 from repro.autotune.corpus import chain_lower
@@ -141,7 +142,11 @@ def test_certificate_invariants_seeded():
 
 
 # ----------------------------------------------------------- bitwise fast
-def _bitwise_cell(a, backend, lower, n_rhs, *, slack=None, cache=None):
+def _bitwise_cell(a, backend, lower, n_rhs, *, slack=None, cache=None,
+                  timed=False):
+    """Elastic == bulk on ``backend``, bit for bit; on the scan backend
+    both also equal ``kernels/ref.py``'s oracle, and so do the timed
+    segment paths when ``timed``."""
     kw = {"interpret": True} if backend == "pallas" else {}
     bulk = TriangularSolver.plan(
         a, strategy="growlocal", k=K, lower=lower, backend=backend,
@@ -163,6 +168,11 @@ def _bitwise_cell(a, backend, lower, n_rhs, *, slack=None, cache=None):
         f"elastic solve diverged from bulk on backend={backend} "
         f"lower={lower} n_rhs={n_rhs}"
     )
+    if backend == "scan":
+        assert np.array_equal(xb, solver_oracle(bulk, b))
+    if timed:
+        for solver in (bulk, el):
+            assert np.array_equal(np.asarray(solver.solve_timed(b)[0]), xb)
 
 
 @pytest.mark.parametrize("backend", ["scan", "pallas"])
@@ -177,8 +187,9 @@ def _bitwise_cell(a, backend, lower, n_rhs, *, slack=None, cache=None):
 )
 def test_elastic_bitwise_fast(make, backend):
     a = make()
-    _bitwise_cell(a, backend, True, 1)
+    _bitwise_cell(a, backend, True, 1, timed=True)
     _bitwise_cell(a, backend, True, 3)
+    _bitwise_cell(a, backend, True, 16)
 
 
 @pytest.mark.parametrize("slack", [1, 2, 5, 16])
@@ -209,8 +220,10 @@ def test_elastic_update_values_bitwise():
             **kw,
         )
         el.numeric_update(a2.data)
-        assert np.array_equal(np.asarray(el.solve(b)),
-                              np.asarray(fresh.solve(b)))
+        x = np.asarray(el.solve(b))
+        assert np.array_equal(x, np.asarray(fresh.solve(b)))
+        if backend == "scan":
+            assert np.array_equal(x, solver_oracle(fresh, b))
 
 
 # ------------------------------------------------------ stats / selection

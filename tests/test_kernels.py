@@ -12,6 +12,8 @@ from repro.kernels.sptrsv import sptrsv_pallas
 from repro.solver import solve_lower_scipy
 from repro.sparse import dag_from_lower_csr, erdos_renyi_lower, narrow_band_lower
 
+from _oracle import oracle_solve
+
 
 def _plan_for(n, density, seed, k=8, width=None):
     L = erdos_renyi_lower(n, density, seed=seed)
@@ -76,19 +78,32 @@ def test_kernel_matches_scipy_nb():
     assert np.abs(x - x_ref).max() / (np.abs(x_ref).max() + 1e-30) < 2e-3
 
 
-def test_kernel_oracle_is_scan_executor():
-    """ref.py and solver.executor implement the same dataflow."""
-    from repro.solver.executor import plan_arrays, solve_with_plan
-
-    L2, plan = _plan_for(150, 0.04, seed=9, k=4)
-    rng = np.random.default_rng(5)
-    b = rng.standard_normal(150)
-    pa = plan_arrays(plan)
-    x1 = np.asarray(solve_with_plan(pa, jnp.asarray(b, jnp.float32)))
-    b_pad = jnp.concatenate(
-        [jnp.asarray(b, jnp.float32), jnp.zeros(1, jnp.float32)]
+@pytest.mark.parametrize("m", [1, 16], ids=["rhs1", "rhs16"])
+@pytest.mark.parametrize("width", [None, 2], ids=["p95", "split"])
+def test_kernel_oracle_is_scan_executor(width, m):
+    """ref.py and solver.executor implement the same dataflow, bit for
+    bit, on a plan with padded lanes and accum chains (the default width,
+    the 95th percentile of row widths, splits the widest rows; ``width=2``
+    most of them). The executor's own layout never gathers the sink row
+    n + 1, and accum lanes, and only they, write to it."""
+    from repro.solver.executor import (
+        elastic_plan_arrays,
+        plan_arrays,
+        solve_with_plan,
     )
-    x2 = np.asarray(
-        sptrsv_ref(pa.row_ids, pa.col_idx, pa.vals, pa.diag, pa.accum, b_pad)
-    )[:150]
-    np.testing.assert_allclose(x1, x2, rtol=1e-6, atol=1e-6)
+
+    L2, plan = _plan_for(150, 0.04, seed=9, k=4, width=width)
+    assert plan.accum.any() and (plan.row_ids == plan.n).any()
+    pa = plan_arrays(plan)
+    ea = elastic_plan_arrays(plan, slack=8)
+    sink = plan.n + 1
+    for arrays in (pa, ea):
+        assert int(np.asarray(arrays.cols).max()) < sink
+        writes_sink = np.asarray(arrays.write_rows) == sink
+        assert np.array_equal(writes_sink, np.asarray(arrays.accum))
+    rng = np.random.default_rng(5)
+    B = rng.standard_normal((150, m)).astype(np.float32)
+    if m == 1:
+        B = B[:, 0]
+    x = np.asarray(solve_with_plan(pa, jnp.asarray(B)))
+    assert np.array_equal(x, oracle_solve(plan, B))

@@ -7,12 +7,21 @@ any shard count). The grid covers corpus x orientation x RHS shape on
 two mesh shapes, plus the elastic fused-exchange path, the
 update_values contract, describe() telemetry and the timed
 per-exchange-round path. Host-side partitioner properties live in
-``test_rowshard.py``."""
+``test_rowshard.py``. Every solve is also held to ``kernels/ref.py``'s
+oracle (``tests/_oracle.py``), bit for bit."""
+import textwrap
+from pathlib import Path
+
 from _mesh import run_in_mesh_subprocess
+
+# makes tests/_oracle.py importable in the subprocess
+_TESTS = f"import sys; sys.path.insert(0, {str(Path(__file__).parent)!r})\n"
 
 
 def _run(code: str, devices: int = 8, timeout: int = 600):
-    return run_in_mesh_subprocess(code, devices=devices, timeout=timeout)
+    return run_in_mesh_subprocess(
+        _TESTS + textwrap.dedent(code), devices=devices, timeout=timeout
+    )
 
 
 def test_rowshard_bitwise_conformance_grid():
@@ -25,6 +34,7 @@ def test_rowshard_bitwise_conformance_grid():
         from repro.serve.service import direct_reference
         from repro.sparse import transpose_csr
         from repro.sparse.generators import erdos_renyi_lower, narrow_band_lower
+        from _oracle import solver_oracle
 
         mats = {
             "er": erdos_renyi_lower(700, 2.5e-3, seed=9),
@@ -51,9 +61,13 @@ def test_rowshard_bitwise_conformance_grid():
                     x1 = np.asarray(s.solve(b1))
                     assert np.array_equal(x1, np.asarray(ref.solve(b1))), (
                         mesh_shape, name, lower, "rhs1")
-                    assert np.array_equal(
-                        np.asarray(s.solve(B)), np.asarray(ref.solve(B))
-                    ), (mesh_shape, name, lower, "mrhs")
+                    assert np.array_equal(x1, solver_oracle(ref, b1)), (
+                        mesh_shape, name, lower, "rhs1 oracle")
+                    X = np.asarray(s.solve(B))
+                    assert np.array_equal(X, np.asarray(ref.solve(B))), (
+                        mesh_shape, name, lower, "mrhs")
+                    assert np.array_equal(X, solver_oracle(ref, B)), (
+                        mesh_shape, name, lower, "mrhs oracle")
                     # canonical same-compiled-family replay, bit for bit
                     assert np.array_equal(
                         x1, np.asarray(direct_reference(s, b1))
@@ -101,6 +115,7 @@ def test_rowshard_update_values_and_timed():
         import numpy as np, jax
         from repro.pipeline import TriangularSolver
         from repro.sparse.generators import erdos_renyi_lower
+        from _oracle import solver_oracle
 
         a = erdos_renyi_lower(600, 3e-3, seed=11)
         mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -109,6 +124,7 @@ def test_rowshard_update_values_and_timed():
             validate="fast")
         b = np.random.default_rng(5).standard_normal(600).astype(np.float32)
         x0 = np.asarray(s.solve(b))
+        assert np.array_equal(x0, solver_oracle(s, b))
 
         # timed path: same bits, one entry per exchange round
         x_t, steps = s.solve_timed(b)
@@ -129,6 +145,7 @@ def test_rowshard_update_values_and_timed():
             a2, k=8, backend="distributed", mesh=mesh, shard="rows")
         x1 = np.asarray(s.solve(b))
         assert np.array_equal(x1, np.asarray(fresh.solve(b)))
+        assert np.array_equal(x1, solver_oracle(fresh, b))
         assert not np.array_equal(x1, x0)
         print("rowshard-update-timed-ok")
     """))

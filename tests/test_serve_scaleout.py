@@ -37,6 +37,8 @@ from repro.serve import (
 from repro.sparse import shifted_coupling_lower
 from repro.sparse.generators import erdos_renyi_lower
 
+from _oracle import solver_oracle
+
 STRATEGY = "wavefront"  # level scheduler: shift-invariant plan shapes
 N = 96
 
@@ -144,14 +146,34 @@ def test_grouped_lane_independence_and_replay(family_solvers):
         assert np.array_equal(replay, fixed)
 
 
-def test_group_bank_bitwise_matches_grouped_solve(family_solvers):
+@pytest.mark.parametrize("width", [None, 2], ids=["plain", "split"])
+def test_group_bank_bitwise_matches_grouped_solve(family, family_solvers,
+                                                  width):
     """The serving fast path (device bank, lanes indexed inside the jit)
     must be bitwise-identical to the stack-per-call ``grouped_solve`` —
     that identity is what lets ``GroupReplay`` verify bank-served
     results. Checked across compositions and bank sizes (pow2 lane
-    padding means P=4 and P=6-padded-to-8 compile different variants)."""
+    padding means P=4 and P=6-padded-to-8 compile different variants),
+    and every column against ``kernels/ref.py``'s oracle of its own
+    plan. The family's rows hold one entry off the diagonal, so the
+    ``split`` case banks four value sets of one Erdos-Renyi pattern at
+    ``width=2`` instead, which splits its wider rows into accum chains."""
+    import dataclasses
+
     from repro.pipeline import GroupBank
 
+    if width is not None:
+        a = erdos_renyi_lower(N, 0.08, seed=3)
+        vrng = np.random.default_rng(5)
+        family_solvers = [
+            TriangularSolver.plan(
+                dataclasses.replace(a, data=a.data * vrng.uniform(0.5, 2, a.nnz)),
+                strategy=STRATEGY, width=width,
+            )
+            for _ in range(4)
+        ]
+        assert all(s.exec_plan.accum.any() for s in family_solvers)
+        assert len({s.width_class for s in family_solvers}) == 1
     rng = np.random.default_rng(4)
     bank = GroupBank()
     for i, s in enumerate(family_solvers):
@@ -164,6 +186,10 @@ def test_group_bank_bitwise_matches_grouped_solve(family_solvers):
             grouped_solve([family_solvers[i] for i in comp], B)
         )
         assert np.array_equal(got, ref), comp
+        for j, i in enumerate(comp):
+            assert np.array_equal(
+                got[:, j], solver_oracle(family_solvers[i], B[:, j])
+            ), (comp, j)
     # membership churn: drop + prune invalidate and rebuild lazily
     rebuilds = bank.rebuilds
     bank.drop(3)

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from _hyp import HAVE_HYPOTHESIS, given, settings, strategies as st
+from _oracle import solver_oracle
 from repro.pipeline import GroupBank, TriangularSolver
 from repro.serve import (
     AdmissionQueue,
@@ -250,6 +251,9 @@ def test_solve_resident_matches_solve_bank_bitwise(family_solvers):
     X_res = np.asarray(bank.solve_resident(lane_keys, B))
     X_bank = np.asarray(bank.solve(lane_keys, np.stack(cols, axis=1)))
     assert X_res.tobytes() == X_bank.tobytes()
+    for j, (key, c) in enumerate(zip(lane_keys, cols)):
+        ref = solver_oracle(family_solvers[key], c)
+        assert X_res[:, j].tobytes() == ref.tobytes(), j
 
 
 def test_neighbor_insert_never_perturbs_occupied_lane(family_solvers):

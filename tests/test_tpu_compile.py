@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may hold the TPU library, and the
 test worker that is handed this file is the one that loads it.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -52,6 +54,7 @@ def one_chip(topo):
 
 
 def _plan_shapes(T, k, W, sharding, lead=()):
+    """The ExecPlan's tensors, as the Pallas kernel takes them."""
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         (*lead, *shape), dt, sharding=sharding
     )
@@ -64,8 +67,23 @@ def _plan_shapes(T, k, W, sharding, lead=()):
     )
 
 
+def _step_shapes(T, k, W, sharding, lead=()):
+    """The scan executor's step layout (``solver.executor``): write
+    rows, w-major flat gather indices, [W, k] values, diag, accum."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        (*lead, *shape), dt, sharding=sharding
+    )
+    return (
+        s((T, k), jnp.int32),
+        s((T, W * k), jnp.int32),
+        s((T, W, k), jnp.float32),
+        s((T, k), jnp.float32),
+        s((T, k), jnp.bool_),
+    )
+
+
 def _rhs(n, m, sharding):
-    shape = (n + 1,) if m == 1 else (n + 1, m)
+    shape = (n,) if m == 1 else (n, m)
     return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
 
@@ -75,9 +93,8 @@ def test_scan_executor_compiles(one_chip, m):
 
     fn = _solve_scan if m == 1 else _solve_scan_mrhs
     compiled = fn.lower(
-        *_plan_shapes(BAND_T, BAND_K, BAND_W, one_chip),
+        *_step_shapes(BAND_T, BAND_K, BAND_W, one_chip),
         _rhs(BAND_N, m, one_chip),
-        n=BAND_N,
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -90,12 +107,70 @@ def test_elastic_scan_executor_compiles(one_chip, m):
     fn = _solve_elastic if m == 1 else _solve_elastic_mrhs
     M = SOLVE_T // SLACK
     compiled = fn.lower(
-        *_plan_shapes(SLACK, SOLVE_K, SOLVE_W, one_chip, lead=(M,)),
+        *_step_shapes(SLACK, SOLVE_K, SOLVE_W, one_chip, lead=(M,)),
         _rhs(SOLVE_N, m, one_chip),
-        n=SOLVE_N,
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _computations(hlo: str) -> dict:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _while_body_gathers_scatters(hlo: str) -> int:
+    """The instructions of the one while loop's body that gather or
+    scatter, themselves or inside the fusion they call (fusions nest)."""
+    comps = _computations(hlo)
+    bodies = [
+        re.search(r"body=%([\w.\-]+)", line).group(1)
+        for lines in comps.values() for line in lines if " while(" in line
+    ]
+    assert len(bodies) == 1, bodies
+
+    def moves(line):
+        if re.search(r" (gather|scatter)\(", line):
+            return True
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        return bool(called) and any(map(moves, comps[called.group(1)]))
+
+    return sum(map(moves, comps[bodies[0]]))
+
+
+# the plan steps one iteration of the compiled loop walks: the elastic
+# scan unrolls a slack window of 8, the bulk scan walks one
+@pytest.mark.parametrize(
+    "name, W, m, window",
+    [("_solve_elastic", 4, 1, SLACK), ("_solve_scan_mrhs", 12, N_RHS, 1)],
+    ids=["elastic", "mrhs"],
+)
+def test_step_body_is_one_gather_and_one_scatter(one_chip, name, W, m,
+                                                 window):
+    """The compiled loop body moves x with at most two device ops per
+    plan step: the gather of every slot's x and the scatter of the
+    results (the rhs is gathered before the loop, and an accum lane
+    writes the sink row instead of reading its row back). Small T, so
+    the compile stays fast."""
+    from repro.solver import executor
+
+    T, k = 1024, 8
+    lead = (T // window, window) if window > 1 else (T,)
+    compiled = getattr(executor, name).lower(
+        *_step_shapes(lead[-1], k, W, one_chip, lead=lead[:-1]),
+        _rhs(T, m, one_chip),
+    ).compile()
+    per_step = _while_body_gathers_scatters(compiled.as_text()) / window
+    assert 0 < per_step <= 2, per_step
 
 
 @pytest.mark.parametrize("m", [1, N_RHS])
@@ -110,7 +185,7 @@ def test_pallas_kernel_compiles(one_chip, m):
     )
     accum = jax.ShapeDtypeStruct(accum.shape, jnp.float32, sharding=one_chip)
     compiled = sptrsv_pallas.lower(
-        row, col, val, diag, accum, _rhs(SOLVE_N, m, one_chip),
+        row, col, val, diag, accum, _rhs(SOLVE_N + 1, m, one_chip),
         steps_per_tile=8, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -125,14 +200,13 @@ def test_banked_grouped_scan_compiles(one_chip, lanes):
     chip's 16 GB."""
     from repro.solver.executor import _solve_scan_banked
 
-    bank = _plan_shapes(SOLVE_T, SOLVE_K, SOLVE_W, one_chip, lead=(1,))
+    bank = _step_shapes(SOLVE_T, SOLVE_K, SOLVE_W, one_chip, lead=(1,))
     perm = jax.ShapeDtypeStruct((1, SOLVE_N), jnp.int32, sharding=one_chip)
     compiled = _solve_scan_banked.lower(
         *bank, perm, perm,
         jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((SOLVE_N, lanes), jnp.float32,
                              sharding=one_chip),
-        n=SOLVE_N,
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
