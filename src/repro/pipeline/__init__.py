@@ -11,6 +11,9 @@ into every example, benchmark and the CG driver:
     x = solver.solve(b)           # b: f[n] or batched f[n, m]
 
     fwd, bwd = factor_pair(Lf)    # L y = b, then L^T x = y (PCG's M^{-1})
+    fwd, bwd = gauss_seidel_pair(A)      # x = bwd.solve(fwd.solve(r)): one
+                                         # symmetric Gauss–Seidel sweep
+                                         # from x = 0
 
 ``strategy="auto"`` hands the choice to the autotuner (``repro.autotune``:
 DAG features -> rule shortlist -> §2.2 cost model; ``tune=True`` adds
@@ -19,7 +22,8 @@ measured trials); the outcome is memoized in the ``PlanCache``.
 Module map:
 
   * ``registry``  — named scheduling strategies behind one signature
-  * ``solver``    — ``TriangularSolver`` / ``factor_pair`` (plan + bind)
+  * ``solver``    — ``TriangularSolver`` / ``factor_pair`` /
+                    ``gauss_seidel_pair`` (plan + bind)
   * ``cache``     — sparsity-pattern-keyed plan cache with hit/miss stats
 """
 from repro.pipeline.cache import CacheStats, PlanCache
@@ -34,6 +38,7 @@ from repro.pipeline.solver import (
     GroupBank,
     TriangularSolver,
     factor_pair,
+    gauss_seidel_pair,
     grouped_solve,
 )
 
@@ -53,5 +58,6 @@ __all__ = [
     "GroupBank",
     "TriangularSolver",
     "factor_pair",
+    "gauss_seidel_pair",
     "grouped_solve",
 ]

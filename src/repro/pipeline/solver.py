@@ -42,6 +42,8 @@ from repro.pipeline.cache import PlanCache
 from repro.pipeline.registry import ScheduleOptions, get_scheduler
 from repro.sparse.csr import (
     CSRMatrix,
+    csr_from_coo,
+    lower_triangle_of,
     pattern_fingerprint,
     permute_symmetric,
     transpose_csr,
@@ -843,4 +845,31 @@ def factor_pair(lf: CSRMatrix, *, cache: Optional[PlanCache] = None, **kw):
     ``(Lf Lf^T)^{-1}``, PCG's preconditioner."""
     fwd = TriangularSolver.plan(lf, lower=True, cache=cache, **kw)
     bwd = TriangularSolver.plan(transpose_csr(lf), lower=False, cache=cache, **kw)
+    return fwd, bwd
+
+
+def gauss_seidel_pair(a: CSRMatrix, *, cache: Optional[PlanCache] = None,
+                      **kw):
+    """Plan the two sweeps of a symmetric Gauss–Seidel smoother on ``a``
+    (``D`` its diagonal, ``L``/``U`` its strictly lower/upper parts):
+    ``fwd`` solves with ``L + D``, ``bwd`` with ``I + D^-1 U``.
+
+    From ``x = 0`` the symmetric sweep (HPCG's ``ComputeSYMGS``: a forward
+    sweep over the rows, then a backward one) is exactly these two solves,
+    ``x = bwd.solve(fwd.solve(r))``: the forward sweep gives
+    ``x1 = (L + D)^-1 r``, and since ``(L + D) x1 = r`` the backward sweep
+    gives ``x2 = (D + U)^-1 (r - L x1) = (D + U)^-1 D x1 = (I + D^-1 U)^-1 x1``.
+    Only from ``x = 0``: a sweep from any other ``x`` is not this pair."""
+    d = a.diagonal()
+    if np.any(d == 0):
+        raise ValueError("Gauss–Seidel needs a non-zero diagonal")
+    rows = a.row_of_entry()
+    upper = a.indices >= rows
+    unit_upper = csr_from_coo(
+        a.n_rows, a.n_cols, rows[upper], a.indices[upper],
+        a.data[upper] / d[rows[upper]],
+    )
+    fwd = TriangularSolver.plan(lower_triangle_of(a), lower=True,
+                                cache=cache, **kw)
+    bwd = TriangularSolver.plan(unit_upper, lower=False, cache=cache, **kw)
     return fwd, bwd

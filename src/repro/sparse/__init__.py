@@ -28,6 +28,7 @@ from repro.sparse.generators import (
     poisson3d_matrix,
     random_spd_band,
     shifted_coupling_lower,
+    stencil27_matrix,
 )
 from repro.sparse.ichol import ichol0
 
@@ -51,5 +52,6 @@ __all__ = [
     "poisson3d_matrix",
     "random_spd_band",
     "shifted_coupling_lower",
+    "stencil27_matrix",
     "ichol0",
 ]

@@ -129,6 +129,37 @@ def poisson3d_matrix(nx: int, ny: int | None = None, nz: int | None = None) -> C
     )
 
 
+def stencil27_matrix(nx: int, ny: int, nz: int) -> CSRMatrix:
+    """HPCG's matrix (``GenerateProblem_ref``, hpcg 3.1): the 27-point
+    stencil on an nx × ny × nz block. Row ``ix + nx * (iy + ny * iz)``
+    (x fastest) holds 26.0 on the diagonal and -1.0 for each neighbour of
+    the 3 × 3 × 3 cube around it that lies inside the block; columns
+    ascending. One rank's local block, halo columns left out."""
+    n = nx * ny * nz
+    iz, iy, ix = np.meshgrid(
+        np.arange(nz), np.arange(ny), np.arange(nx), indexing="ij"
+    )
+    ix, iy, iz = ix.ravel(), iy.ravel(), iz.ravel()
+    row = np.arange(n, dtype=np.int64)
+    rows, cols, vals = [], [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                inside = (
+                    (ix + dx >= 0) & (ix + dx < nx)
+                    & (iy + dy >= 0) & (iy + dy < ny)
+                    & (iz + dz >= 0) & (iz + dz < nz)
+                )
+                r = row[inside]
+                rows.append(r)
+                cols.append(r + dx + nx * (dy + ny * dz))
+                centre = dx == 0 and dy == 0 and dz == 0
+                vals.append(np.full(len(r), 26.0 if centre else -1.0))
+    return csr_from_coo(
+        n, n, np.concatenate(rows), np.concatenate(cols), np.concatenate(vals)
+    )
+
+
 def shifted_coupling_lower(
     n: int, shift: int, *, stride: int = 8, seed: int = 0
 ) -> CSRMatrix:

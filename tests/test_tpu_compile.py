@@ -148,13 +148,15 @@ def _while_body_gathers_scatters(hlo: str) -> int:
 
 
 # the plan steps one iteration of the compiled loop walks: the elastic
-# scan unrolls a slack window of 8, the bulk scan walks one
+# scan unrolls a slack window of 8, the bulk scan walks one; k 128, W 13
+# is the hpcg_104.symgs cell's plan (hdagg on HPCG's 27-point block)
 @pytest.mark.parametrize(
-    "name, W, m, window",
-    [("_solve_elastic", 4, 1, SLACK), ("_solve_scan_mrhs", 12, N_RHS, 1)],
-    ids=["elastic", "mrhs"],
+    "name, k, W, m, window",
+    [("_solve_elastic", 8, 4, 1, SLACK), ("_solve_scan_mrhs", 8, 12, N_RHS, 1),
+     ("_solve_elastic", 128, 13, 1, SLACK)],
+    ids=["elastic", "mrhs", "elastic-k128"],
 )
-def test_step_body_is_one_gather_and_one_scatter(one_chip, name, W, m,
+def test_step_body_is_one_gather_and_one_scatter(one_chip, name, k, W, m,
                                                  window):
     """The compiled loop body moves x with at most two device ops per
     plan step: the gather of every slot's x and the scatter of the
@@ -163,7 +165,7 @@ def test_step_body_is_one_gather_and_one_scatter(one_chip, name, W, m,
     the compile stays fast."""
     from repro.solver import executor
 
-    T, k = 1024, 8
+    T = 1024
     lead = (T // window, window) if window > 1 else (T,)
     compiled = getattr(executor, name).lower(
         *_step_shapes(lead[-1], k, W, one_chip, lead=lead[:-1]),
