@@ -1,6 +1,7 @@
 """Core library: the paper's scheduling contribution.
 
-  * ``grow_local`` — the GrowLocal scheduler (§3, Alg. 3.1)
+  * ``grow_local`` — the GrowLocal scheduler (§3, Alg. 3.1);
+    ``widened_grow_local`` adds the frontier-widening cut where it pays
   * ``funnel_grow_local`` / ``funnel_partition`` / ``coarsen_dag`` /
     ``pull_back_schedule`` — §4 (``core/funnel.py`` / ``core/coarsen.py``)
   * ``apply_reordering`` — §5 locality reordering
@@ -31,7 +32,7 @@ from repro.core.elastic import (
     step_dependencies,
 )
 from repro.core.funnel import funnel_grow_local
-from repro.core.growlocal import grow_local
+from repro.core.growlocal import grow_local, widened_grow_local
 from repro.core.hdagg import hdagg_schedule
 from repro.core.plan import ExecPlan, compile_plan
 from repro.core.reorder import Reordering, apply_reordering, schedule_order
@@ -53,6 +54,7 @@ from repro.core.wavefront import wavefront_schedule
 
 __all__ = [
     "grow_local",
+    "widened_grow_local",
     "funnel_grow_local",
     "hdagg_schedule",
     "spmp_like_schedule",

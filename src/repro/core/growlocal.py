@@ -29,7 +29,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.schedule import DEFAULT_L, Schedule
+from repro import obs
+from repro.core.schedule import DEFAULT_L, Schedule, bsp_cost
 from repro.sparse.dag import SolveDAG
 
 ALPHA_INIT = 20
@@ -52,16 +53,54 @@ def grow_local(
 ) -> Schedule:
     """Run GrowLocal on ``dag`` for ``k`` cores; returns a valid Schedule.
 
-    ``frontier_widening`` (beyond-paper, off by default to stay faithful):
-    on single-/few-source DAGs the paper's worthiness rule never cuts — beta
-    = Omega_1/(Omega_1 + L) increases monotonically while one core's
-    exclusive chain swallows the whole DAG (their SuiteSparse filter,
-    avg wavefront >= 2k, hides this regime). When the DAG has parallelism to
-    unlock (avg wavefront >= 2) but the current superstep keeps less than
-    half the cores busy, we stop growing alpha: the barrier releases the
-    frontier to the free pool and the next superstep engages all cores.
-    EXPERIMENTS.md §Perf quantifies the effect (narrow-band: ~2x model
-    speed-up; ichol grids: serial -> parallel)."""
+    ``frontier_widening`` (beyond-paper; off here, so a direct call is the
+    paper's algorithm): on single-/few-source DAGs the paper's worthiness
+    rule never cuts — beta = Omega_1/(Omega_1 + L) increases monotonically
+    while one core's exclusive chain swallows the whole DAG (their
+    SuiteSparse filter, avg wavefront >= 2k, hides this regime). When the
+    DAG has parallelism to unlock (avg wavefront >= 2) but the current
+    superstep runs on a single core, we stop growing alpha: the barrier
+    releases the frontier to the free pool and the next superstep engages
+    all cores. ``widened_grow_local`` (the registry's ``"growlocal"``)
+    applies the cut where it pays."""
+    return _grow_local(
+        dag, k, L, alpha_init, alpha_growth, worthy_factor, frontier_widening
+    )[0]
+
+
+def widened_grow_local(dag: SolveDAG, k: int, *, L: float = DEFAULT_L) -> Schedule:
+    """GrowLocal with the frontier-widening cut, kept where it pays.
+
+    A run in which the cut never fires is the paper's schedule bit for bit
+    (many-source DAGs). Where it fires, the widened schedule is kept only if
+    its ``bsp_cost`` beats the paper's; the ``obs`` counter
+    ``inspector.growlocal.widen_cut`` then counts the supersteps the cut
+    closed. PERF.md §6 quantifies the effect (the IC(0) factor of a 64^3
+    grid at k 8: one superstep of T = n plan steps -> 77 supersteps, T =
+    35,851)."""
+    widened, cuts = _grow_local(
+        dag, k, L, ALPHA_INIT, ALPHA_GROWTH, WORTHY_FACTOR, True
+    )
+    if cuts == 0:
+        return widened
+    faithful = grow_local(dag, k, L=L)
+    if bsp_cost(dag, widened, L) < bsp_cost(dag, faithful, L):
+        obs.counter_add("inspector.growlocal.widen_cut", cuts)
+        return widened
+    return faithful
+
+
+def _grow_local(
+    dag: SolveDAG,
+    k: int,
+    L: float,
+    alpha_init: int,
+    alpha_growth: float,
+    worthy_factor: float,
+    frontier_widening: bool,
+) -> Tuple[Schedule, int]:
+    """``grow_local``'s body; also returns how many supersteps the
+    frontier-widening cut closed."""
     n = dag.n
     if n == 0:
         return Schedule(
@@ -71,9 +110,11 @@ def grow_local(
             sigma=np.zeros(0, np.int32),
             rank=np.zeros(0, np.int64),
             n_supersteps=0,
-        )
-    weights = dag.weights
-    child_ptr, child_idx = dag.child_ptr, dag.child_idx
+        ), 0
+    # Python lists, not arrays: the loops below touch one element at a
+    # time, where a list index costs a fraction of a NumPy scalar access
+    weights = dag.weights.tolist()
+    child_ptr, child_idx = dag.child_ptr.tolist(), dag.child_idx.tolist()
 
     widen_cut = False
     if frontier_widening:
@@ -82,29 +123,25 @@ def grow_local(
         widen_cut = average_wavefront_size(dag) >= 2.0
 
     # --- global (cross-superstep) state -----------------------------------
-    final_remaining = dag.in_degrees().astype(np.int64)  # unfinalized parents
-    scheduled = np.zeros(n, dtype=bool)
-    pi = np.full(n, -1, dtype=np.int32)
-    sigma = np.full(n, -1, dtype=np.int32)
-    rank = np.zeros(n, dtype=np.int64)
-    free_heap: List[int] = np.nonzero(final_remaining == 0)[0].tolist()
+    in_deg = dag.in_degrees()
+    final_remaining = in_deg.tolist()  # unfinalized parents
+    scheduled = [False] * n
+    pi = [-1] * n
+    sigma = [-1] * n
+    rank = [0] * n
+    free_heap: List[int] = np.nonzero(in_deg == 0)[0].tolist()
     heapq.heapify(free_heap)
 
-    # --- per-iteration scratch (reset via touched lists) ------------------
-    cur_done = np.zeros(n, dtype=np.int64)  # parents assigned this iteration
-    claimed = np.full(n, _FREE, dtype=np.int64)
-    iter_tag = np.zeros(n, dtype=np.int64)  # last iteration id touching v
-    assigned_tag = np.zeros(n, dtype=np.int64)  # last iteration id assigning v
+    # --- per-iteration scratch (reset lazily via iter_tag) ----------------
+    cur_done = [0] * n  # parents assigned this iteration
+    claimed = [_FREE] * n
+    iter_tag = [0] * n  # last iteration id touching v
+    assigned_tag = [0] * n  # last iteration id assigning v
     iteration_id = 0
 
     n_scheduled = 0
     superstep = 0
-
-    def _touch(v: int):
-        if iter_tag[v] != iteration_id:
-            iter_tag[v] = iteration_id
-            cur_done[v] = 0
-            claimed[v] = _FREE
+    cuts = 0
 
     while n_scheduled < n:
         alpha = float(alpha_init)
@@ -117,7 +154,7 @@ def grow_local(
             assignment: List[Tuple[int, int]] = []  # (vertex, core) in order
             popped_free: List[int] = []
             excl_heaps: List[List[int]] = [[] for _ in range(k)]
-            omega = np.zeros(k, dtype=np.float64)
+            omega = [0.0] * k
 
             def _next_vertex(p: int) -> int:
                 """Rule I pop for core p; -1 if nothing assignable."""
@@ -140,9 +177,11 @@ def grow_local(
                 assigned_tag[v] = iteration_id
                 assignment.append((v, p))
                 omega[p] += weights[v]
-                lo, hi = child_ptr[v], child_ptr[v + 1]
-                for u in child_idx[lo:hi]:
-                    _touch(u)
+                for u in child_idx[child_ptr[v] : child_ptr[v + 1]]:
+                    if iter_tag[u] != iteration_id:
+                        iter_tag[u] = iteration_id
+                        cur_done[u] = 0
+                        claimed[u] = _FREE
                     cur_done[u] += 1
                     if claimed[u] == _FREE:
                         claimed[u] = p
@@ -153,7 +192,7 @@ def grow_local(
                         and claimed[u] == p
                         and not scheduled[u]
                     ):
-                        heapq.heappush(excl_heaps[p], int(u))
+                        heapq.heappush(excl_heaps[p], u)
 
             # I. assign up to alpha vertices to core 1 (index 0)
             quota = max(1, int(alpha))
@@ -170,24 +209,25 @@ def grow_local(
                         break
                     _assign(v, p)
 
-            # II. parallelization score
-            total_w = float(omega.sum())
-            max_w = float(omega.max())
+            # II. parallelization score (weights are whole numbers, so the
+            # sum is exact in any order)
+            total_w = sum(omega)
+            max_w = max(omega)
             beta = total_w / (max_w + L) if (max_w + L) > 0 else 0.0
             total_assigned = len(assignment)
 
             first_iteration = last_worthy is None
             worthy = first_iteration or beta >= worthy_factor * best_beta
-            if widen_cut and not first_iteration:
+            if widen_cut and worthy and not first_iteration:
                 # economics of the cut: a barrier (price L) only pays off if
-                # the superstep already carries >= L weight on under-utilized
-                # cores — then stop growing and let the barrier release the
-                # frontier to the free pool. (The unconditional cut was
-                # tried and refuted: it drowns in barrier cost — see
-                # EXPERIMENTS.md §Perf, scheduler iteration log.)
-                active = int((omega > 0).sum())
+                # the superstep already carries >= L weight on a single
+                # core — then stop growing and let the barrier release the
+                # frontier to the free pool. (An unconditional cut drowns
+                # in barrier cost.)
+                active = sum(w > 0 for w in omega)
                 if active <= 1 and total_w >= L:
-                    worthy = False
+                    worthy = False  # closes this superstep below
+                    cuts += 1
             best_beta = max(best_beta, beta)
 
             exhausted = n_scheduled + total_assigned >= n
@@ -214,7 +254,7 @@ def grow_local(
         # --- finalize the superstep ---------------------------------------
         for v in restore:
             heapq.heappush(free_heap, v)
-        chain_pos = np.zeros(k, dtype=np.int64)
+        chain_pos = [0] * k
         newly_ready: List[int] = []
         for (v, p) in finalize:
             scheduled[v] = True
@@ -224,15 +264,19 @@ def grow_local(
             chain_pos[p] += 1
             n_scheduled += 1
         for (v, p) in finalize:
-            lo, hi = child_ptr[v], child_ptr[v + 1]
-            for u in child_idx[lo:hi]:
+            for u in child_idx[child_ptr[v] : child_ptr[v + 1]]:
                 final_remaining[u] -= 1
                 if final_remaining[u] == 0 and not scheduled[u]:
-                    newly_ready.append(int(u))
+                    newly_ready.append(u)
         for u in newly_ready:
             heapq.heappush(free_heap, u)
         superstep += 1
 
     return Schedule(
-        n=n, k=k, pi=pi, sigma=sigma, rank=rank, n_supersteps=superstep
-    )
+        n=n,
+        k=k,
+        pi=np.array(pi, dtype=np.int32),
+        sigma=np.array(sigma, dtype=np.int32),
+        rank=np.array(rank, dtype=np.int64),
+        n_supersteps=superstep,
+    ), cuts

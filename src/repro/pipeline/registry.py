@@ -32,6 +32,7 @@ from repro.core import (
     serial_schedule,
     spmp_like_schedule,
     wavefront_schedule,
+    widened_grow_local,
 )
 from repro.sparse.dag import SolveDAG
 
@@ -131,7 +132,7 @@ def schedule(
 
 @register_scheduler("growlocal")
 def _growlocal(dag: SolveDAG, o: ScheduleOptions) -> Schedule:
-    return grow_local(dag, o.k, L=o.L)
+    return widened_grow_local(dag, o.k, L=o.L)
 
 
 @register_scheduler("funnel-gl")

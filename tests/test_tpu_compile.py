@@ -20,9 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, SingleDeviceSharding
 
-# chip_smoke.py shapes: the 64^3 Poisson IC(0) factor planned by
-# strategy="auto" (growlocal, k=8, W=4, one step per row, elastic slack
-# 8), and the n = 1e6 narrow-band matrix planned by growlocal at k=4
+# chip_smoke.py shapes: the 64^3 Poisson IC(0) factor at k=8, W=4,
+# elastic slack 8, with T = n, one step per row (the serial plan GrowLocal
+# gave it before its frontier-widening cut: the largest T at this n), and
+# the n = 1e6 narrow-band matrix planned by growlocal at k=4
 SOLVE_N, SOLVE_T, SOLVE_K, SOLVE_W, SLACK = 262_144, 262_144, 8, 4, 8
 BAND_N, BAND_T, BAND_K, BAND_W = 1_000_000, 251_094, 4, 4
 N_RHS = 16
