@@ -28,7 +28,6 @@ TABLES = {
     "t77": ("table77_amortization", "Table 7.6 amortization threshold"),
     "t78": ("table78_blocks", "Table 7.7 block-parallel scheduling"),
     "t7x": ("table7x_auto", "Auto-strategy vs best/worst fixed (corpus)"),
-    "roofline": ("kernel_roofline", "Kernel roofline"),
 }
 
 

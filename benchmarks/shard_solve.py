@@ -31,7 +31,7 @@ from __future__ import annotations
 import os
 
 # must precede the first jax import: jax locks the host device count at
-# first init (same isolation launch/dryrun.py uses)
+# first init (same isolation tests/_mesh.py uses)
 os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
 )

@@ -5,7 +5,7 @@ portability claim: one schedule, many executors):
   * numpy-serial  (the Serial baseline),
   * jnp-scan      (XLA:CPU vectorized executor),
   * pallas-interp (the TPU kernel executed in interpret mode — correctness
-    path; its TPU roofline is in EXPERIMENTS.md §Roofline).
+    path; its TPU roofline share is the chip benchmark's ``solve_roofline``).
 """
 from __future__ import annotations
 

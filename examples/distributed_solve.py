@@ -1,6 +1,6 @@
 """Distributed SpTRSV: the BSP schedule executed across a device mesh,
 barriers realized as all-gathers (DESIGN.md §3). Runs on 8 forced host
-devices — the same code path the 512-chip dry-run lowers. The whole
+devices — the same code path a TPU mesh runs. The whole
 matrix -> plan -> mesh binding is one ``TriangularSolver.plan`` call with
 ``backend="distributed"``.
 

@@ -7,12 +7,12 @@ sequential scan over each device's chain; the synchronization barrier is an
 L = barrier cost becomes the ICI all-gather latency — see DESIGN.md §3).
 
 The jitted graph contains exactly ``n_supersteps`` all-gathers: GrowLocal's
-barrier reduction is visible directly in the lowered HLO (the §Roofline
-collective term counts these). Multi-RHS (SpTRSM) batches shard over the
-``data`` axis, giving the full production mesh a workload.
+barrier reduction is visible directly in the lowered HLO. Multi-RHS
+(SpTRSM) batches shard over the ``data`` axis, giving the full production
+mesh a workload.
 
-``distributed_input_specs`` / ``lower_distributed_solve`` are consumed by
-``launch/dryrun.py`` for the paper-workload dry-run cells.
+``distributed_input_specs`` / ``lower_distributed_solve`` lower the solve
+without running it; the mesh compile tests use them.
 
 This module is the device half of the ``distributed`` entry in
 ``repro.backends`` — bind through the registry
@@ -188,7 +188,7 @@ def distributed_input_specs(spec: DistPlanSpec, mesh: Mesh):
 
 
 def lower_distributed_solve(spec: DistPlanSpec, mesh: Mesh):
-    """.lower() the distributed solve on the given mesh (dry-run path)."""
+    """.lower() the distributed solve on the given mesh (compile only)."""
     solve = build_distributed_solver(spec, mesh)
     args = distributed_input_specs(spec, mesh)
     return jax.jit(solve).lower(*args)

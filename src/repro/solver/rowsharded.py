@@ -331,7 +331,7 @@ def halo_args_for_round(rsp: RowShardPlan, r: int, mode="ring"):
 def lower_rowsharded_solve(
     rsp: RowShardPlan, mesh: Mesh, *, batch=0, dtype=np.float32, mode="ring"
 ):
-    """.lower() the sharded solve on the given mesh (dry-run path): real
+    """.lower() the sharded solve on the given mesh (compile only): real
     partition tensors, jit + shard_map, no execution."""
     spec = rowshard_spec(rsp, mode=mode, batch=batch)
     solve = build_rowsharded_solver(spec, mesh)

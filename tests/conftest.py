@@ -1,7 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests must see the real
 single-device CPU; multi-device tests spawn subprocesses (see
-tests/test_distributed.py) and the 512-device dry-run lives in
-src/repro/launch/dryrun.py."""
+tests/_mesh.py and tests/test_distributed.py)."""
 import numpy as np
 import pytest
 

@@ -16,12 +16,13 @@ has no TPU; interpret executes the same kernel logic through the Pallas
 interpreter, so grid coverage carries to the kernel path), and the
 ``distributed`` backend runs its corpus sweep in a subprocess with a
 forced multi-device CPU mesh (jax locks the device count at first init —
-the same isolation tests/test_distributed.py uses). The grid is
+the same isolation tests/test_distributed.py uses). The solve grids are
 corpus-wide (7 strategies x 9 matrices x 2 orientations x 2 RHS shapes
 per in-process backend) and therefore ``slow``-marked; plans are shared
 through one module-level ``PlanCache`` so each (strategy, matrix,
 orientation, backend) is scheduled and compiled once across the RHS
-parametrization.
+parametrization. The schedule-validity grid (7 x 9 schedules, each
+checked by ``check_validity``) takes seconds, so it runs in tier-1.
 """
 import numpy as np
 import pytest
@@ -37,8 +38,6 @@ from repro.pipeline import (
     schedule,
 )
 from repro.sparse import dag_from_lower_csr, transpose_csr
-
-pytestmark = pytest.mark.slow
 
 STRATEGIES = available_strategies()  # all 7 registered strategies
 # every registered backend is covered: single-device ones in-process,
@@ -99,6 +98,7 @@ def test_schedule_validity(name, strategy):
     assert s.n == dag.n and s.n_supersteps >= 1
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("backend", IN_PROCESS_BACKENDS)
 @pytest.mark.parametrize("n_rhs", [1, 3], ids=["rhs1", "mrhs"])
 @pytest.mark.parametrize("lower", [True, False], ids=["lower", "upper"])
@@ -129,6 +129,7 @@ def test_solve_matches_reference(name, strategy, lower, n_rhs, backend):
 
 
 # ------------------------------------------- distributed backend (3rd cell)
+@pytest.mark.slow
 def test_distributed_backend_conformance_grid():
     """The distributed executor's corpus sweep — the third registered
     backend joins the conformance grid (ROADMAP open item). Needs a
