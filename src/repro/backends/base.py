@@ -11,7 +11,12 @@ the ``if/elif`` binding block in ``pipeline/solver.py``:
     (and ignores if irrelevant): ``dtype``, ``steps_per_tile``,
     ``interpret``, ``mesh``.
   * ``BoundSolve`` — an immutable bound solver:
-      - ``solve(b)`` for ``b`` f[n] or f[n, m] (multi-RHS);
+      - ``solve(b)`` for ``b`` f[n] or f[n, m] (multi-RHS), x in plan
+        row order;
+      - ``solve_raw(b)`` — x in the bound's own output order, where
+        ``out_index`` (int32[n], or None for plan order) puts each plan
+        row; a caller that permutes x anyway folds ``out_index`` into
+        its own gather and skips the bound's;
       - ``update_values(data) -> BoundSolve`` refreshes the numeric
         values *on device* — a gather of the new entry data through the
         plan's ``val_src``/``diag_src`` maps — and returns a NEW bound
@@ -112,12 +117,17 @@ class BoundSolve(abc.ABC):
     # that only implements ``solve_grouped`` must not set this flag).
     supports_grouped: bool = False
 
+    # where ``solve_raw`` puts each plan row: a device int32[n], or None
+    # when its result is in plan order (then ``solve_raw`` is ``solve``)
+    out_index = None
+
     @classmethod
     def solve_grouped(cls, bounds, b_cols):
         """Solve lane j of ``b_cols`` f[g, n] (plan row order) against
         ``bounds[j]`` — one dispatch for the whole group. All bounds must
         share one width class (identical plan tensor shapes, same dtype).
-        Returns x f[g, n]. Only meaningful when ``supports_grouped``."""
+        Returns x f[g, ·], lane j in ``bounds[j]``'s ``solve_raw``
+        order. Only meaningful when ``supports_grouped``."""
         raise NotImplementedError(
             f"backend {cls.backend!r} does not support width-class "
             "grouped solves"
@@ -126,8 +136,10 @@ class BoundSolve(abc.ABC):
     @classmethod
     def stack_bank(cls, bounds, perms, invs):
         """Stack one width class's bounds into an opaque device bank
-        (lane axis first) with per-lane row permutations ``perms``/
-        ``invs`` — restacked only when membership changes. Returned
+        (lane axis first) with per-lane row permutations ``perms``
+        (caller row order -> plan row order) and ``invs`` (caller row ->
+        its position in the lane's ``solve_raw`` result) — restacked
+        only when membership changes. Returned
         value is backend-defined; it is only ever passed back to
         ``solve_bank``."""
         raise NotImplementedError(
@@ -209,19 +221,24 @@ class BoundSolve(abc.ABC):
     def solve(self, b):
         """Solve for ``b`` f[n] or f[n, m]; returns x shaped like b."""
 
+    def solve_raw(self, b):
+        """``solve`` with x in the bound's own output order (see
+        ``out_index``)."""
+        return self.solve(b)
+
     def solve_timed(self, b):
-        """``solve`` plus per-step device timings: returns ``(x, steps)``
-        where ``steps`` is a list of JSON-ready dicts, finest granularity
-        the backend can observe. This base fallback times the whole
-        blocked solve as ONE step (backends without segmented dispatch —
-        pallas tiles, shard_map supersteps — still report a synchronized
-        wall-clock); the scan backend overrides it with per-superstep
-        (bulk) / per-macro-step (elastic) segments."""
+        """``solve_raw`` plus per-step device timings: returns ``(x,
+        steps)`` where ``steps`` is a list of JSON-ready dicts, finest
+        granularity the backend can observe. This base fallback times
+        the whole blocked solve as ONE step (backends without segmented
+        dispatch — pallas tiles, shard_map supersteps — still report a
+        synchronized wall-clock); the scan backend overrides it with
+        per-superstep (bulk) / per-macro-step (elastic) segments."""
         import time as _time
 
         with obs.span("executor.solve_timed", cat="executor", n=self.n):
             t0 = _time.perf_counter_ns()
-            x = self.solve(b)
+            x = self.solve_raw(b)
             try:
                 x.block_until_ready()
             except AttributeError:  # plain ndarray result

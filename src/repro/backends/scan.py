@@ -28,15 +28,23 @@ class ScanBoundSolve(BoundSolve):
         self._np_dtype = np_dtype
         self.n = pa.n
         self.n_entries = n_entries
+        # solve_raw answers in step-slot order (solver.executor)
+        self.out_index = pa.out_index
 
     def solve(self, b):
         from repro.solver.executor import solve_with_plan
 
         return solve_with_plan(self._pa, b)
 
+    def solve_raw(self, b):
+        from repro.solver.executor import solve_slots
+
+        return solve_slots(self._pa, b)
+
     def solve_timed(self, b):
         """Per-superstep timed solve: one jitted segment per superstep
-        of the plan (see ``solver.executor.solve_with_plan_timed``)."""
+        of the plan (see ``solver.executor.solve_with_plan_timed``);
+        x in slot order, as ``solve_raw``."""
         from repro.solver.executor import solve_with_plan_timed
 
         return solve_with_plan_timed(self._pa, b)
@@ -119,10 +127,13 @@ class ScanBoundSolve(BoundSolve):
             "n_steps": T,
             "k": k,
             "W": W,
+            "layout": "slots",
+            "slots": T * k,
             "dtype": np.dtype(self._np_dtype).name,
             "device_bytes": int(
                 sum(a.size * a.dtype.itemsize
-                    for a in self._pa[:5] + (self._val_src, self._diag_src))
+                    for a in self._pa[:5] + (self._pa.out_index,
+                                          self._val_src, self._diag_src))
             ),
         }
 
@@ -146,6 +157,7 @@ class ElasticScanBoundSolve(BoundSolve):
         self._np_dtype = np_dtype
         self.n = ea.n
         self.n_entries = n_entries
+        self.out_index = ea.out_index  # as ScanBoundSolve's
         # runtime side of the elastic certificate: what timed solves
         # actually executed, reported by describe() next to the
         # certificate's predicted fusion ratios (fresh per bound; an
@@ -157,8 +169,14 @@ class ElasticScanBoundSolve(BoundSolve):
 
         return solve_with_elastic(self._ea, b)
 
+    def solve_raw(self, b):
+        from repro.solver.executor import solve_slots
+
+        return solve_slots(self._ea, b)
+
     def solve_timed(self, b):
-        """Per-macro-step timed elastic solve; records the actual
+        """Per-macro-step timed elastic solve (x in slot order, as
+        ``solve_raw``); records the actual
         macro-step count into the bound's runtime telemetry so
         ``describe()`` can put measured execution next to the
         certificate's predicted ``barrier_fusion``."""
@@ -211,10 +229,13 @@ class ElasticScanBoundSolve(BoundSolve):
             "slack": S,
             "k": k,
             "W": W,
+            "layout": "slots",
+            "slots": M * S * k,
             "dtype": np.dtype(self._np_dtype).name,
             "device_bytes": int(
                 sum(a.size * a.dtype.itemsize
-                    for a in self._ea[:5] + (self._val_src, self._diag_src))
+                    for a in self._ea[:5] + (self._ea.out_index,
+                                          self._val_src, self._diag_src))
             ),
             # certificate (predicted) vs runtime (measured, from
             # solve_timed): the elastic fused-barrier claim, executed

@@ -32,10 +32,10 @@ Each shard gets a *local* ``ExecPlan`` over its own index space:
 ``[0, n_loc)`` owned rows (global-id order), ``[n_loc, n_loc+n_halo)``
 halo slots for remote rows it reads, and a trailing scratch slot that
 padding reads/writes (always zero).  Row/column ids are remapped to
-local slots so the per-shard executor is the ordinary scan executor —
-same gathers, same fixed-order lane reduction, same scatter — which is
-what makes the sharded solve *bitwise-identical* to the single-device
-scan solve.
+local slots so the per-shard executor runs the scan executor's step
+arithmetic — same gathers, same fixed-order lane reduction — writing
+its results with a scatter into those slots, which is what makes the
+sharded solve *bitwise-identical* to the single-device scan solve.
 
 For every exchange round the partitioner emits exact
 (source shard, row) -> (dest shard, slot) index tensors in two lowered

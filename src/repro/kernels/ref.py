@@ -26,7 +26,7 @@ def sptrsv_ref(row_ids, col_idx, vals, diag, accum, b_pad):
         x, acc = carry
         rows, cols, v, d, a = inp
         # fixed left-to-right lane reduction, matching the scan executor's
-        # _step_single exactly — elementwise IEEE ops per lane keep the
+        # _values_single exactly — elementwise IEEE ops per lane keep the
         # oracle bitwise shape-independent (see solver/executor.py)
         for w in range(v.shape[1]):
             acc = acc + v[:, w] * x[cols[:, w]]

@@ -21,13 +21,13 @@ no vector gather from VMEM at arbitrary addresses, so this per-element
 form is what lowers; it is not tuned for speed.
 
 Arithmetic order per (row, RHS) is the scan executor's exactly
-(``solver.executor._step_single``): ``acc += v[w] * x[col[w]]`` for
+(``solver.executor._values_single``): ``acc += v[w] * x[col[w]]`` for
 w = 0..W-1 in order, then ``(b[row] - acc) / diag``. Lanes of one step
 are independent rows of one superstep, so running them one after another
-reads the same values the scan's gather-then-scatter step reads. Padding
-lanes (row id n) are skipped: in the scan they only write +0.0 into the
-scratch slot, which the kernel's zero-initialized scratch slot already
-holds.
+reads the same values the scan's gather-then-write step reads. Padding
+lanes (row id n) are skipped: in the scan they only write +0.0 into
+slots no gather reads, and the kernel's padding gathers read its
+zero-initialized scratch slot.
 """
 from __future__ import annotations
 

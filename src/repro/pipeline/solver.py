@@ -178,7 +178,12 @@ class TriangularSolver:
         bound solve's device-side ``update_values`` gather. The
         ``backend.bind`` span covers all of it: the elastic certificate
         (when ``plan()`` has not attached it for the verifier), the row
-        permutations' and the backend's device puts."""
+        permutations' and the backend's device puts.
+
+        ``_inv`` maps each caller row to its place in the bound's
+        ``solve_raw`` result: the inverse row permutation composed with
+        the bound's ``out_index`` (the scan's step-slot order), so a
+        solve issues one gather back to the caller's order, not two."""
         from repro.backends import get_backend
 
         plan = self.exec_plan
@@ -189,7 +194,7 @@ class TriangularSolver:
             n=plan.n,
             slack=self._slack,
             shard=self._shard,
-        ):
+        ) as sp:
             if self._slack > 0 and (
                 plan.elastic is None or plan.elastic.slack != self._slack
             ):
@@ -197,10 +202,6 @@ class TriangularSolver:
 
                 # attached, so that ExecPlan.stats() reads the same one
                 plan.elastic = elastic_transform(plan, self._slack)
-            total_inv = np.empty_like(total_perm)
-            total_inv[total_perm] = np.arange(len(total_perm))
-            self._perm = jnp.asarray(total_perm, jnp.int32)
-            self._inv = jnp.asarray(total_inv, jnp.int32)
             self._bound = get_backend(self.backend).bind(
                 plan,
                 dtype=self.dtype,
@@ -210,6 +211,15 @@ class TriangularSolver:
                 slack=self._slack,
                 shard=self._shard,
             )
+            total_inv = np.empty_like(total_perm)
+            total_inv[total_perm] = np.arange(len(total_perm))
+            self._perm = jnp.asarray(total_perm, jnp.int32)
+            self._inv = jnp.asarray(total_inv, jnp.int32)
+            out = self._bound.out_index
+            if out is not None:
+                self._inv = out[self._inv]
+                slots = self._bound.describe()["slots"]
+                sp.set(slots=slots, slot_overhead=slots / plan.n)
 
     @property
     def bound(self):
@@ -273,7 +283,7 @@ class TriangularSolver:
         b = self._check_b(b)
         # the enqueue: JAX returns before the device has finished
         with obs.span("executor.dispatch", cat="executor", n=self.n):
-            x = self._bound.solve(b[self._perm])
+            x = self._bound.solve_raw(b[self._perm])
             return x[self._inv]
 
     def solve_timed(self, b):

@@ -7,15 +7,20 @@ mode (partitioning in ``repro.core.rowshard``; bind through
 Each ``model``-axis device owns one shard: a contiguous block of
 ``k_local`` schedule cores and their rows. Its x-buffer is *resident* —
 ``[owned | halo | scratch]`` local slots — and a solve is the ordinary
-scan over the shard's local ``ExecPlan`` (the exact ``_step_single`` /
-``_step_mrhs`` bodies and step layout from ``solver.executor``, so
-per-row arithmetic is bitwise-identical to the single-chip scan),
-punctuated by one halo exchange per barrier round. The scan carries one
-slot past the local slots: the sink that the step's ``accum`` lanes
-write. Unlike the model-axis executor
-(``solver.distributed``), which ``all_gather``s every core's xv at every
-superstep, the exchange moves ONLY the boundary values some other shard
-actually reads — static index tensors computed at partition time.
+scan over the shard's local ``ExecPlan`` (the exact ``_values_single`` /
+``_values_mrhs`` arithmetic from ``solver.executor``, so per-row
+arithmetic is bitwise-identical to the single-chip scan), punctuated by
+one halo exchange per barrier round. Unlike the single-chip scan, which
+keeps x in step-slot order and writes each step's results with one
+contiguous update, a shard keeps x in its compact local slot space —
+the halo slots the exchange fills sit among them — so a step scatters
+its k results to their local slots. The scan carries one slot past the
+local slots: the sink that the step's ``accum`` lanes write.
+
+Unlike the model-axis executor (``solver.distributed``), which
+``all_gather``s every core's xv at every superstep, the exchange moves
+ONLY the boundary values some other shard actually reads — static index
+tensors computed at partition time.
 
 Two lowerings of the same exchange plan:
 
@@ -44,12 +49,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.rowshard import RowShardPlan
 from repro.solver.executor import (
-    _step_mrhs,
-    _step_single,
-    laid_out,
-    pad_rhs,
+    _values_mrhs,
+    _values_single,
     to_device,
-    walk,
+    w_major,
 )
 
 
@@ -117,12 +120,59 @@ def rowshard_spec(rsp: RowShardPlan, *, mode="ring", batch=0) -> RowShardSpec:
     )
 
 
+def laid_out_rows(plan):
+    """A shard plan's step tensors, host-side, in the row layout: the
+    scan executor's w-major step layout, but ``write_rows`` int32[T, k]
+    holds each lane's local slot (padding: the scratch slot n, the
+    shard plan's own padding; ``accum`` lanes: the sink n + 1, which no
+    gather reads) and ``cols`` local slots (padding: n)."""
+    n, k, W = plan.n, plan.k, plan.W
+    cols = w_major(plan.col_idx)
+    return (
+        np.where(plan.accum, n + 1, plan.row_ids),
+        cols.reshape(cols.shape[0], W * k),
+        w_major(plan.vals),
+        plan.diag,
+        plan.accum,
+    )
+
+
 def rowshard_plan_args(rsp: RowShardPlan, dtype=jnp.float32):
-    """Stack the per-shard plans, in the scan executor's step layout,
+    """Stack the per-shard plans, in the row layout (``laid_out_rows``),
     into device operands [n_shards, T, ...] (sharded over ``model`` by
     shard_map)."""
-    per_shard = zip(*(laid_out(s) for s in rsp.shards))
+    per_shard = zip(*(laid_out_rows(s) for s in rsp.shards))
     return to_device([np.stack(t) for t in per_shard], dtype)
+
+
+def _scatter_step(values):
+    """The row layout's step: ``values``' k results scattered to the
+    lanes' local slots. Padded lanes share the scratch slot and accum
+    lanes the sink, so indices are not unique; a plain scatter keeps
+    them well-defined (they all write junk to those two slots)."""
+
+    def step(x, acc, rows, cols, v, d, a, bt):
+        val, acc = values(x, acc, cols, v, d, a, bt)
+        return x.at[rows].set(val), acc
+
+    return step
+
+
+def with_sink(x):
+    """``x`` f[slots(, m)] with one zero slot appended: the sink."""
+    return jnp.concatenate([x, jnp.zeros((1, *x.shape[1:]), x.dtype)])
+
+
+def walk_rows(step, carry, steps, b_pad):
+    """Run ``step`` over ``steps`` = (write_rows, cols, vals, diag,
+    accum), leading axis first, from ``carry`` = (x, acc); b is gathered
+    for every step at once, before the loop."""
+
+    def body(carry, inp):
+        return step(*carry, *inp), None
+
+    carry, _ = jax.lax.scan(body, carry, (*steps, b_pad[steps[0]]))
+    return carry
 
 
 def rowshard_halo_args(rsp: RowShardPlan, mode="ring"):
@@ -161,9 +211,10 @@ def _exchange_ring(x, tables, hops_static, n_shards):
     tables are positionally aligned by construction (sorted by global
     row id within each src->dst pair; dst = src + h is a bijection per
     hop), so the position IS the routing. Padded positions send the
-    scratch slot — provably +0.0 (padding-lane induction, see
-    ``solver.executor``) — and land on the receiver's scratch slot:
-    ragged per-shard halo counts stay bitwise harmless."""
+    scratch slot — provably +0.0 (by induction: a padded lane gathers
+    the scratch slot with value 0 and writes (0 - 0) / 1 back) — and
+    land on the receiver's scratch slot: ragged per-shard halo counts
+    stay bitwise harmless."""
     for (h, _), (ss, rt) in zip(hops_static, tables):
         perm = [(i, (i + h) % n_shards) for i in range(n_shards)]
         got = jax.lax.ppermute(x[ss[0]], "model", perm=perm)
@@ -213,14 +264,14 @@ def _run_round(spec, step, x, acc, plan, b_pad, r):
     lo, hi = sb[eb[r]], sb[eb[r + 1]]
     if hi == lo:
         return x, acc
-    return walk(step, (x, acc), tuple(t[lo:hi] for t in plan), b_pad)
+    return walk_rows(step, (x, acc), tuple(t[lo:hi] for t in plan), b_pad)
 
 
 def _local_operands(args):
     """The shard's plan tensors and its rhs with the sink row appended,
     from shard_map's operands (whose size-1 shard axis is stripped)."""
     plan = tuple(a[0] for a in args[:5])
-    return plan, pad_rhs(args[-1][0], 1)
+    return plan, with_sink(args[-1][0])
 
 
 def build_rowsharded_solver(spec: RowShardSpec, mesh: Mesh):
@@ -245,7 +296,7 @@ def build_rowsharded_solver(spec: RowShardSpec, mesh: Mesh):
     def body(*args):
         halo = _group_tables(spec, args[5:-1])
         plan, b_pad = _local_operands(args)
-        step = _step_mrhs if mrhs else _step_single
+        step = _scatter_step(_values_mrhs if mrhs else _values_single)
         x = jnp.zeros_like(b_pad)
         acc = jnp.zeros((spec.k_local, *b_pad.shape[1:]), b_pad.dtype)
         for r in range(spec.n_rounds):
@@ -287,8 +338,8 @@ def build_rowsharded_round(spec: RowShardSpec, mesh: Mesh, r: int):
         halo = args[5:5 + n_halo_args]
         plan, b_pad = _local_operands(args[:-1])
         # the carried x gets the sink row for this round's scan only
-        x = pad_rhs(args[-1][0], 1)
-        step = _step_mrhs if mrhs else _step_single
+        x = with_sink(args[-1][0])
+        step = _scatter_step(_values_mrhs if mrhs else _values_single)
         acc = jnp.zeros((spec.k_local, *b_pad.shape[1:]), b_pad.dtype)
         x, acc = _run_round(spec, step, x, acc, plan, b_pad, r)
         x = x[:-1]

@@ -1,12 +1,13 @@
 """The bit-for-bit reference of the scan executor's step.
 
 ``kernels/ref.py::sptrsv_ref`` walks an ExecPlan's own [T, k, W]
-tensors with the step the scan executor ran before its step layout: W
-gathers of x, b and ``x[rows]`` read inside the step, one scatter. The
-executor's one gather, hoisted b and sink row must reproduce it bit for
-bit, so every executor path (bulk, elastic, timed, banked, resident,
-row-sharded, after a value refresh) is checked against it here, one rhs
-column at a time.
+tensors, with x in plan row order, with the step the scan executor ran
+before its step layout: W gathers of x, b and ``x[rows]`` read inside
+the step, one scatter. The executor's one gather, hoisted b, slot layout
+(and, row-sharded, sink row) must reproduce it bit for bit, so every
+executor path (bulk, elastic, timed, banked, resident, row-sharded,
+after a value refresh) is checked against it here, one rhs column at a
+time.
 """
 from __future__ import annotations
 
@@ -39,5 +40,6 @@ def solver_oracle(solver, b) -> np.ndarray:
     """``oracle_solve`` through a ``TriangularSolver``'s permutation: b
     and x in the caller's row order."""
     b = np.asarray(b, np.float32)
-    x = oracle_solve(solver.exec_plan, b[np.asarray(solver._perm)])
-    return x[np.asarray(solver._inv)]
+    perm = np.asarray(solver._perm)
+    x = oracle_solve(solver.exec_plan, b[perm])
+    return x[np.argsort(perm)]

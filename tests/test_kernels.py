@@ -84,8 +84,9 @@ def test_kernel_oracle_is_scan_executor(width, m):
     """ref.py and solver.executor implement the same dataflow, bit for
     bit, on a plan with padded lanes and accum chains (the default width,
     the 95th percentile of row widths, splits the widest rows; ``width=2``
-    most of them). The executor's own layout never gathers the sink row
-    n + 1, and accum lanes, and only they, write to it."""
+    most of them). The executor's slot layout gathers only the slots of
+    finishing lanes and the zero slot past the last step, and accum
+    lanes read b's zero row n, as padded lanes do."""
     from repro.solver.executor import (
         elastic_plan_arrays,
         plan_arrays,
@@ -96,11 +97,14 @@ def test_kernel_oracle_is_scan_executor(width, m):
     assert plan.accum.any() and (plan.row_ids == plan.n).any()
     pa = plan_arrays(plan)
     ea = elastic_plan_arrays(plan, slack=8)
-    sink = plan.n + 1
     for arrays in (pa, ea):
-        assert int(np.asarray(arrays.cols).max()) < sink
-        writes_sink = np.asarray(arrays.write_rows) == sink
-        assert np.array_equal(writes_sink, np.asarray(arrays.accum))
+        zero = np.asarray(arrays.write_rows).size
+        finishing = set(np.asarray(arrays.out_index).tolist())
+        read = set(np.unique(np.asarray(arrays.cols)).tolist())
+        assert read <= finishing | {zero} and zero in read
+        rows = np.asarray(arrays.write_rows)
+        accum = np.asarray(arrays.accum)
+        assert (rows[accum] == plan.n).all() and (rows <= plan.n).all()
     rng = np.random.default_rng(5)
     B = rng.standard_normal((150, m)).astype(np.float32)
     if m == 1:

@@ -69,8 +69,8 @@ def _plan_shapes(T, k, W, sharding, lead=()):
 
 
 def _step_shapes(T, k, W, sharding, lead=()):
-    """The scan executor's step layout (``solver.executor``): write
-    rows, w-major flat gather indices, [W, k] values, diag, accum."""
+    """The scan executor's step layout (``solver.executor``): b's row
+    per lane, w-major flat gather slots, [W, k] values, diag, accum."""
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         (*lead, *shape), dt, sharding=sharding
     )
@@ -129,9 +129,10 @@ def _computations(hlo: str) -> dict:
     return comps
 
 
-def _while_body_gathers_scatters(hlo: str) -> int:
-    """The instructions of the one while loop's body that gather or
-    scatter, themselves or inside the fusion they call (fusions nest)."""
+def _while_body_ops(hlo: str, op: str) -> int:
+    """The instructions of the one while loop's body that are an ``op``
+    (``gather``, ``scatter``, ``dynamic-update-slice``), themselves or
+    inside the fusion they call (fusions nest)."""
     comps = _computations(hlo)
     bodies = [
         re.search(r"body=%([\w.\-]+)", line).group(1)
@@ -140,7 +141,7 @@ def _while_body_gathers_scatters(hlo: str) -> int:
     assert len(bodies) == 1, bodies
 
     def moves(line):
-        if re.search(r" (gather|scatter)\(", line):
+        if re.search(rf" {op}\(", line):
             return True
         called = re.search(r"calls=%([\w.\-]+)", line)
         return bool(called) and any(map(moves, comps[called.group(1)]))
@@ -159,21 +160,23 @@ def _while_body_gathers_scatters(hlo: str) -> int:
 )
 def test_step_body_is_one_gather_and_one_scatter(one_chip, name, k, W, m,
                                                  window):
-    """The compiled loop body moves x with at most two device ops per
-    plan step: the gather of every slot's x and the scatter of the
-    results (the rhs is gathered before the loop, and an accum lane
-    writes the sink row instead of reading its row back). Small T, so
-    the compile stays fast."""
+    """The compiled loop body moves x with two device ops per plan step:
+    the gather of every slot's x and one contiguous write of the k
+    results (a dynamic-update-slice; x is kept in step-slot order, so
+    the step scatters nothing). The rhs is gathered before the loop,
+    and an accum lane writes its own slot instead of reading its row
+    back. Small T, so the compile stays fast."""
     from repro.solver import executor
 
     T = 1024
     lead = (T // window, window) if window > 1 else (T,)
-    compiled = getattr(executor, name).lower(
+    hlo = getattr(executor, name).lower(
         *_step_shapes(lead[-1], k, W, one_chip, lead=lead[:-1]),
         _rhs(T, m, one_chip),
-    ).compile()
-    per_step = _while_body_gathers_scatters(compiled.as_text()) / window
-    assert 0 < per_step <= 2, per_step
+    ).compile().as_text()
+    assert _while_body_ops(hlo, "scatter") == 0
+    assert _while_body_ops(hlo, "gather") == window
+    assert _while_body_ops(hlo, "dynamic-update-slice") == window
 
 
 @pytest.mark.parametrize("m", [1, N_RHS])
