@@ -14,6 +14,8 @@ into every example, benchmark and the CG driver:
     fwd, bwd = gauss_seidel_pair(A)      # x = bwd.solve(fwd.solve(r)): one
                                          # symmetric Gauss–Seidel sweep
                                          # from x = 0
+    vc = VCycle.build((nx, ny, nz))      # z = vc(r): HPCG's 4-level
+                                         # multigrid V-cycle
 
 ``strategy="auto"`` hands the choice to the autotuner (``repro.autotune``:
 DAG features -> rule shortlist -> §2.2 cost model; ``tune=True`` adds
@@ -25,6 +27,8 @@ Module map:
   * ``solver``    — ``TriangularSolver`` / ``factor_pair`` /
                     ``gauss_seidel_pair`` (plan + bind)
   * ``cache``     — sparsity-pattern-keyed plan cache with hit/miss stats
+  * ``multigrid`` — ``SymGSSweep`` (the sweep from any x) and ``VCycle``
+                    (HPCG's ``ComputeMG_ref`` on the device)
 """
 from repro.pipeline.cache import CacheStats, PlanCache
 from repro.pipeline.registry import (
@@ -41,6 +45,8 @@ from repro.pipeline.solver import (
     gauss_seidel_pair,
     grouped_solve,
 )
+
+from repro.pipeline.multigrid import SymGSSweep, VCycle
 
 # the cheap pattern handle (re-exported so serving clients can fingerprint
 # once and submit by handle without importing the sparse layer)
@@ -60,4 +66,6 @@ __all__ = [
     "factor_pair",
     "gauss_seidel_pair",
     "grouped_solve",
+    "SymGSSweep",
+    "VCycle",
 ]

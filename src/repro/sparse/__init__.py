@@ -23,6 +23,8 @@ from repro.sparse.dag import (
 )
 from repro.sparse.generators import (
     erdos_renyi_lower,
+    hpcg_f2c,
+    mg_block_shapes,
     narrow_band_lower,
     poisson2d_matrix,
     poisson3d_matrix,
@@ -47,6 +49,8 @@ __all__ = [
     "longest_path_length",
     "average_wavefront_size",
     "erdos_renyi_lower",
+    "hpcg_f2c",
+    "mg_block_shapes",
     "narrow_band_lower",
     "poisson2d_matrix",
     "poisson3d_matrix",

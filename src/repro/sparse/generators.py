@@ -160,6 +160,38 @@ def stencil27_matrix(nx: int, ny: int, nz: int) -> CSRMatrix:
     )
 
 
+def mg_block_shapes(nx: int, ny: int, nz: int, levels: int) -> list:
+    """The block of each level of HPCG's multigrid hierarchy, finest
+    first: each coarse level halves every side (``GenerateCoarseProblem``,
+    hpcg 3.1), and its matrix is ``stencil27_matrix`` of that block, not a
+    Galerkin product. Refuses a block whose sides ``2^(levels - 1)`` does
+    not divide, as HPCG does."""
+    if levels < 1:
+        raise ValueError(f"levels must be at least 1; got {levels}")
+    step = 1 << (levels - 1)
+    if min(nx, ny, nz) < 1 or nx % step or ny % step or nz % step:
+        raise ValueError(
+            f"a {levels}-level hierarchy needs sides divisible by {step}; "
+            f"got {nx} x {ny} x {nz}"
+        )
+    return [(nx >> i, ny >> i, nz >> i) for i in range(levels)]
+
+
+def hpcg_f2c(nx: int, ny: int, nz: int) -> np.ndarray:
+    """HPCG's ``f2cOperator`` for a fine block nx × ny × nz (even sides):
+    int32[n_coarse], the fine row of each coarse row. Coarse point
+    (ixc, iyc, izc), row ``ixc + nxc (iyc + nyc izc)``, sits on fine point
+    (2 ixc, 2 iyc, 2 izc), row ``2 ixc + nx (2 iyc + ny 2 izc)``."""
+    if nx % 2 or ny % 2 or nz % 2:
+        raise ValueError(f"f2c needs even sides; got {nx} x {ny} x {nz}")
+    izc, iyc, ixc = np.meshgrid(
+        np.arange(nz // 2), np.arange(ny // 2), np.arange(nx // 2),
+        indexing="ij",
+    )
+    fine = 2 * ixc + nx * (2 * iyc + ny * 2 * izc)
+    return fine.ravel().astype(np.int32)
+
+
 def shifted_coupling_lower(
     n: int, shift: int, *, stride: int = 8, seed: int = 0
 ) -> CSRMatrix:

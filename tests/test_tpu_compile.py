@@ -218,6 +218,29 @@ def test_banked_grouped_scan_compiles(one_chip, lanes):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
 
 
+# HPCG's 104^3 block (the V-cycle cell's finest level): A's rows at the
+# 52^3 coarse points for the restriction, and D^-1 U on the whole level
+HPCG_N, HPCG_NC = 104 ** 3, 52 ** 3
+
+
+@pytest.mark.parametrize("rows, width", [(HPCG_NC, 27), (HPCG_N, 13)],
+                         ids=["restriction", "upper"])
+def test_multigrid_spmv_compiles(one_chip, rows, width):
+    """The V-cycle's ELL SpMV: one gather of x at every [width, rows]
+    slot and a sum over the width, no scatter."""
+    from repro.solver.ell import _spmv_ell
+
+    compiled = _spmv_ell.lower(
+        jax.ShapeDtypeStruct((width, rows), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((width, rows), jnp.float32, sharding=one_chip),
+        _rhs(HPCG_N, 1, one_chip),
+    ).compile()
+    hlo = compiled.as_text()
+    assert " gather(" in hlo and " scatter(" not in hlo
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2e9
+
+
 @pytest.fixture(scope="module")
 def band_plan():
     """The ``--chips 4`` phase's plan: growlocal at k=4 on the n = 1e6
